@@ -93,12 +93,13 @@ def run() -> list[str]:
     # concurrent submit_many has every item in flight before collecting,
     # serialized drains one at a time.  Each item carries its OWN state so
     # the image's donated buffers are never re-dispatched.
-    from repro.core import (EdgeSystem, ExecutorClass, ServiceSpec,
-                            WorkloadClass)
+    from repro.core import (EdgeSystem, ExecutorClass, NodeCapacity,
+                            ServiceSpec, WorkloadClass)
     from repro.serving.router import make_stream_builder
 
     system = EdgeSystem()
-    system.add_node("edge0").add_node("edge1")
+    node = NodeCapacity(hbm_bytes=16 << 30)      # logical edge nodes
+    system.add_node("edge0", node).add_node("edge1", node)
     system.register_builder("stream", WorkloadClass.LIGHT,
                             make_stream_builder(system.registry, scfg))
     system.apply(ServiceSpec(name="stream-analytics", workload=w,

@@ -17,8 +17,8 @@ import jax.numpy as jnp
 
 from benchmarks.common import csv_line, stats_suffix
 from repro.core import (ContainerExecutor, EdgeSystem, ExecutorClass,
-                        POLICIES, QoSClass, ServiceSpec, Workload,
-                        WorkloadClass, WorkloadKind)
+                        NodeCapacity, POLICIES, QoSClass, ServiceSpec,
+                        Workload, WorkloadClass, WorkloadKind)
 
 import numpy as np
 
@@ -37,7 +37,8 @@ def run() -> list[str]:
     for pname, pcls in POLICIES.items():
         system = EdgeSystem(policy=pcls())
         for i in range(N_NODES):
-            system.add_node(f"worker{i}")
+            system.add_node(f"worker{i}",
+                            NodeCapacity(hbm_bytes=16 << 30))
         system.register_builder("generic", WorkloadClass.HEAVY, _builder)
 
         spec = ServiceSpec(
@@ -127,8 +128,6 @@ def run_tenants() -> str:
     """Mixed GUARANTEED/BEST_EFFORT load: per-tenant p95 latency, a Jain
     fairness index over per-tenant mean latency, and the preemption path
     (a saturating BEST_EFFORT tenant cannot refuse a GUARANTEED apply)."""
-    from repro.core import NodeCapacity
-
     system = EdgeSystem()
     for i in range(N_NODES):
         # 4 instance slots per node: saturation takes a handful of filler

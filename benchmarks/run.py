@@ -58,6 +58,8 @@ def write_bench_json(rows: list, path: str = BENCH_JSON) -> dict:
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_fleet, bench_paged_serving,
                             bench_trace_replay, fig3_container_heavy,
                             fig4_unikernel_light, fig5_hybrid_saving,

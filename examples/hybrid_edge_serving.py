@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_reduced_config
-from repro.core import (EdgeSystem, LeastLoadedPolicy, Workload,
-                        WorkloadKind)
+from repro.core import (EdgeSystem, LeastLoadedPolicy, NodeCapacity,
+                        Workload, WorkloadKind)
 from repro.data import stream as stream_lib
 from repro.serving import router
 
@@ -30,7 +30,7 @@ def main():
     # ---- edge cluster: 1 manager + 4 workers (paper §III-D)
     system = EdgeSystem(policy=LeastLoadedPolicy())
     for i in range(4):
-        system.add_node(f"worker{i}")
+        system.add_node(f"worker{i}", NodeCapacity(hbm_bytes=16 << 30))
 
     heavy_cfg = get_reduced_config("edge-cv-heavy")
     light_cfg = get_reduced_config("edge-stream-light")

@@ -13,7 +13,8 @@ orchestrator, image registry and work queue.  The core loop is:
                             WorkloadKind, WorkloadClass)
 
     system = EdgeSystem()                      # 1. build the system
-    system.add_node("edge0")                   # 2. register nodes
+    system.add_node("edge0")                   # 2. register nodes (capacity
+                                               #    read from the device)
     system.register_builder(kind, wclass, builder)   # 3. teach it to build
     system.apply(ServiceSpec(name="svc", workload=..., replicas=2))
     result = system.submit(workload, args)     # routed, least-inflight
@@ -33,8 +34,8 @@ sys.path.insert(0, "src")
 import numpy as np
 
 from repro.configs import get_reduced_config
-from repro.core import (EdgeSystem, ExecutorClass, ServiceSpec, Workload,
-                        WorkloadClass, WorkloadKind)
+from repro.core import (EdgeSystem, ExecutorClass, NodeCapacity,
+                        ServiceSpec, Workload, WorkloadClass, WorkloadKind)
 from repro.data.tokens import make_lm_iterator
 from repro.launch.mesh import make_test_mesh
 from repro.serving.router import make_engine_builder
@@ -63,7 +64,7 @@ def main():
 
     # 3) declare the serving service and submit prompts through the system
     system = EdgeSystem()
-    system.add_node("edge0")
+    system.add_node("edge0", NodeCapacity(hbm_bytes=16 << 30))  # logical
     system.register_builder(
         "decode", WorkloadClass.HEAVY,
         make_engine_builder(cfg, max_slots=2, max_seq=64,

@@ -11,8 +11,8 @@ sys.path.insert(0, "src")
 
 import jax.numpy as jnp
 
-from repro.core import (EdgeSystem, ExecutorClass, ServiceSpec, Workload,
-                        WorkloadClass, WorkloadKind)
+from repro.core import (EdgeSystem, ExecutorClass, NodeCapacity,
+                        ServiceSpec, Workload, WorkloadClass, WorkloadKind)
 from repro.data import stream as stream_lib
 from repro.serving.router import make_stream_builder
 
@@ -21,7 +21,8 @@ def main():
     scfg = stream_lib.StreamConfig(num_users=32, batch_records=64)
 
     system = EdgeSystem()
-    system.add_node("edge0").add_node("edge1")
+    node = NodeCapacity(hbm_bytes=16 << 30)      # logical edge nodes
+    system.add_node("edge0", node).add_node("edge1", node)
     system.register_builder("stream", WorkloadClass.LIGHT,
                             make_stream_builder(system.registry, scfg))
 

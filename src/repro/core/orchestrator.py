@@ -338,9 +338,13 @@ class Orchestrator:
                 f"admission refused {name!r} on {node_id}: "
                 f"{decision.reason}")
         node = self.nodes[node_id]
-        if rec.prebuilt is not None and node.mesh is None:
-            executor, rec.prebuilt = rec.prebuilt, None
-        else:
+        # the probe build lives on the default device: only a mesh-less
+        # node adopts it; otherwise drop it before building on the node's
+        # devices, so it does not pin a second copy on the default device
+        executor, rec.prebuilt = rec.prebuilt, None
+        if node.mesh is not None:
+            executor = None
+        if executor is None:
             executor = rec.factory(node.mesh)
         dep = Deployment(name, spec.name, node_id, executor, rec.footprint,
                          spec)

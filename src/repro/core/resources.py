@@ -3,30 +3,49 @@
 The paper's manager watches CPU/RAM per Raspberry Pi; here the scarce
 resources per node (mesh slice) are HBM bytes and sustained FLOP/s.  The
 monitor tracks commitments (deployed executor footprints + in-flight work)
-and answers admission queries.  Real telemetry plugs in through ``observe``;
-tests drive it synthetically.
+and answers admission queries.  A node backed by accelerators reads its
+capacity from the devices (``NodeCapacity.of_devices``); logical nodes in
+tests and CPU demos declare an explicit one.
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Dict, Optional
+from typing import Dict, Iterable
 
-# v5e chip constants (roofline/analysis.py uses the same numbers)
-HBM_PER_CHIP = 16 * 2 ** 30
-FLOPS_PER_CHIP = 197e12
+# Published peak dense bf16 FLOP/s per chip, keyed by ``Device.device_kind``.
+# Source: Google Cloud TPU documentation, "TPU v5e" (197 TFLOP/s bf16).
+PEAK_FLOPS_BY_KIND = {
+    "TPU v5 lite": 197e12,
+}
 
 
 @dataclasses.dataclass
 class NodeCapacity:
+    hbm_bytes: int
     chips: int = 1
-    hbm_bytes: int = HBM_PER_CHIP
-    flops_per_s: float = FLOPS_PER_CHIP
+    flops_per_s: float = 0.0
 
     @classmethod
-    def for_chips(cls, chips: int) -> "NodeCapacity":
-        return cls(chips=chips, hbm_bytes=chips * HBM_PER_CHIP,
-                   flops_per_s=chips * FLOPS_PER_CHIP)
+    def of_devices(cls, devices: Iterable) -> "NodeCapacity":
+        """Capacity of the accelerators a node owns, read from the devices:
+        HBM is the runtime's ``memory_stats()["bytes_limit"]`` and FLOP/s
+        the published peak for the device kind.  A device kind without a
+        published peak, or one that reports no memory limit (the CPU
+        backend), raises — such nodes pass an explicit capacity."""
+        devices = list(devices)
+        hbm, flops = 0, 0.0
+        for d in devices:
+            peak = PEAK_FLOPS_BY_KIND.get(d.device_kind)
+            stats = d.memory_stats() or {}
+            if peak is None or "bytes_limit" not in stats:
+                raise ValueError(
+                    f"no known capacity for device kind {d.device_kind!r} "
+                    f"(peaks known for {sorted(PEAK_FLOPS_BY_KIND)}); pass "
+                    f"an explicit NodeCapacity")
+            hbm += int(stats["bytes_limit"])
+            flops += peak
+        return cls(hbm_bytes=hbm, chips=len(devices), flops_per_s=flops)
 
 
 @dataclasses.dataclass

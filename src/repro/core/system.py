@@ -5,7 +5,7 @@ of hand-assembling orchestrator + manager + registry + queue.  The facade
 owns the whole stack and exposes the declarative surface:
 
     system = EdgeSystem(policy=LeastLoadedPolicy())
-    system.add_node("worker0")
+    system.add_node("worker0")           # capacity read from the device
     system.register_builder("stream", WorkloadClass.LIGHT, builder)
     system.apply(ServiceSpec(name="analytics",
                              workload=Workload("fitbit",
@@ -60,9 +60,14 @@ class EdgeSystem:
     # -------------------------------------------------------------- cluster
     def add_node(self, node_id: str,
                  capacity: Optional[NodeCapacity] = None, mesh=None):
-        self.orchestrator.add_node(node_id,
-                                   capacity or NodeCapacity.for_chips(1),
-                                   mesh=mesh)
+        """Register a node.  Without an explicit ``capacity`` it is read
+        from the node's devices: the mesh's, or the default device."""
+        if capacity is None:
+            import jax
+            devices = mesh.devices.flat if mesh is not None \
+                else jax.devices()[:1]
+            capacity = NodeCapacity.of_devices(devices)
+        self.orchestrator.add_node(node_id, capacity, mesh=mesh)
         return self
 
     # ------------------------------------------------------------- services

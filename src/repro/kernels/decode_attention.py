@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import pl_scratch
 
@@ -25,7 +26,7 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref,
             *, sm_scale: float, softcap: float, window: int,
-            block_k: int, n_blocks: int):
+            block_k: int, n_blocks: int, hkv: int):
     ik = pl.program_id(1)
 
     @pl.when(ik == 0)
@@ -40,7 +41,7 @@ def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref,
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
-    valid = valid_ref[0]
+    valid = valid_ref[pl.program_id(0) // hkv]
     pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = pos < valid
     if window > 0:
@@ -94,22 +95,25 @@ def decode_attention(
 
     kernel = functools.partial(
         _kernel, sm_scale=scale, softcap=softcap, window=window,
-        block_k=bk, n_blocks=n_blocks)
+        block_k=bk, n_blocks=n_blocks, hkv=Hkv)
 
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # cache_len
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, ik: (bh // Hkv,)),
-            pl.BlockSpec((1, G, D), lambda bh, ik: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda bh, ik: (bh, ik, 0)),
+            pl.BlockSpec((1, G, D), lambda bh, ik, cl: (bh, 0, 0)),
+            pl.BlockSpec((1, bk, D), lambda bh, ik, cl: (bh, ik, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda bh, ik, cl: (bh, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, Dv), lambda bh, ik: (bh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, G, Dv), lambda bh, ik, cl: (bh, 0, 0)),
         scratch_shapes=[
             pl_scratch((G, Dv)), pl_scratch((G, 1)), pl_scratch((G, 1)),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dv), q.dtype),
         interpret=interpret,
-    )(cache_len, qr, kr, vr)
+    )(cache_len.astype(jnp.int32), qr, kr, vr)
     return out.reshape(B, Hq, Dv)

@@ -19,15 +19,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _kernel(q_pos_ref, kv_pos_ref, valid_ref,
+def _kernel(valid_ref, q_pos_ref, kv_pos_ref,
             q_ref, k_ref, v_ref, o_ref, lse_ref,
             acc_ref, m_ref, l_ref,
             *, sm_scale: float, causal: bool, window: int, softcap: float,
-            n_kv_blocks: int, use_valid: bool):
+            n_kv_blocks: int, use_valid: bool, hq: int):
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -42,15 +43,15 @@ def _kernel(q_pos_ref, kv_pos_ref, valid_ref,
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
-    qp = q_pos_ref[0][:, None]                           # [bq, 1]
-    kp = kv_pos_ref[0][None, :]                          # [1, bk]
+    qp = q_pos_ref[0]                                    # [bq, 1]
+    kp = kv_pos_ref[0]                                   # [1, bk]
     mask = jnp.ones_like(s, dtype=jnp.bool_)
     if causal:
         mask &= kp <= qp
     if window > 0:
         mask &= qp - kp < window
     if use_valid:
-        mask &= kp < valid_ref[0]
+        mask &= kp < valid_ref[pl.program_id(0) // hq]
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]                                  # [bq, 1]
@@ -69,7 +70,7 @@ def _kernel(q_pos_ref, kv_pos_ref, valid_ref,
         lsafe = jnp.where(l == 0.0, 1.0, l)
         o = acc_ref[...] / lsafe
         o_ref[0] = jnp.where(l == 0.0, 0.0, o).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(lsafe))[:, 0]
+        lse_ref[0] = m_ref[...] + jnp.log(lsafe)
 
 
 def flash_attention(  # analysis: oracle=mha
@@ -137,33 +138,42 @@ def flash_attention(  # analysis: oracle=mha
 
     kernel = functools.partial(
         _kernel, sm_scale=scale, causal=causal, window=window,
-        softcap=softcap, n_kv_blocks=n_k, use_valid=use_valid)
+        softcap=softcap, n_kv_blocks=n_k, use_valid=use_valid, hq=Hq)
 
-    out = pl.pallas_call(
-        kernel,
+    # Mosaic tiling: the last two block dims must be (8, 128)-aligned or
+    # span the whole array dim.  Per-row vectors therefore ride as columns
+    # ([.., T, 1]: q positions, lse) or rows ([.., 1, T]: kv positions),
+    # and the per-sequence valid length is a scalar-prefetch (SMEM) input.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # kv_valid_len
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh // Hq, iq)),
-            pl.BlockSpec((1, bk), lambda bh, iq, ik: (bh // Hq, ik)),
-            pl.BlockSpec((1,), lambda bh, iq, ik: (bh // Hq,)),
-            pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (kv_head(bh), ik, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda bh, iq, ik: (kv_head(bh), ik, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, iq, ik, vl: (bh // Hq, iq, 0)),
+            pl.BlockSpec((1, 1, bk), lambda bh, iq, ik, vl: (bh // Hq, 0, ik)),
+            pl.BlockSpec((1, bq, D), lambda bh, iq, ik, vl: (bh, iq, 0)),
+            pl.BlockSpec((1, bk, D),
+                         lambda bh, iq, ik, vl: (kv_head(bh), ik, 0)),
+            pl.BlockSpec((1, bk, Dv),
+                         lambda bh, iq, ik, vl: (kv_head(bh), ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, Dv), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hq, Tq_p, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B * Hq, Tq_p), jnp.float32),
+            pl.BlockSpec((1, bq, Dv), lambda bh, iq, ik, vl: (bh, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, iq, ik, vl: (bh, iq, 0)),
         ],
         scratch_shapes=[
             pl_scratch((bq, Dv)), pl_scratch((bq, 1)), pl_scratch((bq, 1)),
         ],
-        interpret=interpret,
     )
-    out, lse = out(q_positions, kv_positions, kv_valid_len, qr, kr, vr)
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B * Hq, Tq_p, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B * Hq, Tq_p, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )(kv_valid_len.astype(jnp.int32), q_positions[:, :, None],
+      kv_positions[:, None, :], qr, kr, vr)
 
     out = out.reshape(B, Hq, Tq_p, Dv).transpose(0, 2, 1, 3)[:, :Tq]
     if return_lse:
@@ -173,8 +183,5 @@ def flash_attention(  # analysis: oracle=mha
 
 
 def pl_scratch(shape):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover — interpret-only environments
-        return pl.MemorySpace.ANY(shape, jnp.float32)  # type: ignore
+    """f32 VMEM scratch buffer (interpret mode emulates VMEM on the host)."""
+    return pltpu.VMEM(shape, jnp.float32)

@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import (NEG_INF, flash_attention,
                                            pl_scratch)
@@ -30,10 +31,11 @@ from repro.kernels.flash_attention import (NEG_INF, flash_attention,
 # dq kernel
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_pos_ref, kv_pos_ref, valid_ref,
+def _dq_kernel(valid_ref, q_pos_ref, kv_pos_ref,
                q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
                dq_ref, acc_ref,
-               *, sm_scale, causal, window, softcap, n_kv_blocks, use_valid):
+               *, sm_scale, causal, window, softcap, n_kv_blocks, use_valid,
+               hq):
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -44,8 +46,8 @@ def _dq_kernel(q_pos_ref, kv_pos_ref, valid_ref,
     k = k_ref[0].astype(jnp.float32)                     # [bk, D]
     v = v_ref[0].astype(jnp.float32)                     # [bk, Dv]
     do = do_ref[0].astype(jnp.float32)                   # [bq, Dv]
-    lse = lse_ref[0][:, None]                            # [bq, 1]
-    dsum = dsum_ref[0][:, None]                          # [bq, 1]
+    lse = lse_ref[0]                                     # [bq, 1]
+    dsum = dsum_ref[0]                                   # [bq, 1]
 
     s_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
     if softcap > 0.0:
@@ -55,15 +57,15 @@ def _dq_kernel(q_pos_ref, kv_pos_ref, valid_ref,
     else:
         s, dcap = s_raw, None
 
-    qp = q_pos_ref[0][:, None]
-    kp = kv_pos_ref[0][None, :]
+    qp = q_pos_ref[0]                                    # [bq, 1]
+    kp = kv_pos_ref[0]                                   # [1, bk]
     mask = jnp.ones_like(s, dtype=jnp.bool_)
     if causal:
         mask &= kp <= qp
     if window > 0:
         mask &= qp - kp < window
     if use_valid:
-        mask &= kp < valid_ref[0]
+        mask &= kp < valid_ref[pl.program_id(0) // hq]
 
     p = jnp.where(mask, jnp.exp(s - lse), 0.0)           # [bq, bk]
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
@@ -81,10 +83,11 @@ def _dq_kernel(q_pos_ref, kv_pos_ref, valid_ref,
 # dkv kernel
 # ---------------------------------------------------------------------------
 
-def _dkv_kernel(q_pos_ref, kv_pos_ref, valid_ref,
+def _dkv_kernel(valid_ref, q_pos_ref, kv_pos_ref,
                 q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc,
-                *, sm_scale, causal, window, softcap, n_q_steps, use_valid):
+                *, sm_scale, causal, window, softcap, n_q_steps, use_valid,
+                hkv):
     iq = pl.program_id(2)
 
     @pl.when(iq == 0)
@@ -96,8 +99,8 @@ def _dkv_kernel(q_pos_ref, kv_pos_ref, valid_ref,
     k = k_ref[0].astype(jnp.float32)                     # [bk, D]
     v = v_ref[0].astype(jnp.float32)                     # [bk, Dv]
     do = do_ref[0, 0].astype(jnp.float32)                # [bq, Dv]
-    lse = lse_ref[0, 0][:, None]
-    dsum = dsum_ref[0, 0][:, None]
+    lse = lse_ref[0, 0]                                  # [bq, 1]
+    dsum = dsum_ref[0, 0]                                # [bq, 1]
 
     s_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
     if softcap > 0.0:
@@ -107,15 +110,15 @@ def _dkv_kernel(q_pos_ref, kv_pos_ref, valid_ref,
     else:
         s, dcap = s_raw, None
 
-    qp = q_pos_ref[0][:, None]
-    kp = kv_pos_ref[0][None, :]
+    qp = q_pos_ref[0]                                    # [bq, 1]
+    kp = kv_pos_ref[0]                                   # [1, bk]
     mask = jnp.ones_like(s, dtype=jnp.bool_)
     if causal:
         mask &= kp <= qp
     if window > 0:
         mask &= qp - kp < window
     if use_valid:
-        mask &= kp < valid_ref[0]
+        mask &= kp < valid_ref[pl.program_id(0) // hkv]
 
     p = jnp.where(mask, jnp.exp(s - lse), 0.0)           # [bq, bk]
     dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
@@ -183,10 +186,15 @@ def flash_attention_bwd(
                                constant_values=jnp.iinfo(jnp.int32).max - 1)
     Tq_p, Tk_p = Tq + pq, Tk + pk
 
+    # per-row vectors in the forward kernel's Mosaic-legal layouts:
+    # q positions / lse / dsum as columns, kv positions as rows
     qr = q.transpose(0, 2, 1, 3).reshape(B * Hq, Tq_p, D)
     dor = do.transpose(0, 2, 1, 3).reshape(B * Hq, Tq_p, Dv)
-    lser = lse.transpose(0, 2, 1).reshape(B * Hq, Tq_p)
-    dsr = dsum.transpose(0, 2, 1).reshape(B * Hq, Tq_p)
+    lser = lse.transpose(0, 2, 1).reshape(B * Hq, Tq_p, 1)
+    dsr = dsum.transpose(0, 2, 1).reshape(B * Hq, Tq_p, 1)
+    qpos = q_positions[:, :, None]                        # [B, Tq_p, 1]
+    kvpos = kv_positions[:, None, :]                      # [B, 1, Tk_p]
+    valid = kv_valid_len.astype(jnp.int32)
     kr = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Tk_p, D)
     vr = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Tk_p, Dv)
     n_q, n_k = Tq_p // bq, Tk_p // bk
@@ -198,67 +206,82 @@ def flash_attention_bwd(
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=scale, causal=causal,
                           window=window, softcap=softcap, n_kv_blocks=n_k,
-                          use_valid=use_valid),
-        grid=(B * Hq, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh // Hq, iq)),
-            pl.BlockSpec((1, bk), lambda bh, iq, ik: (bh // Hq, ik)),
-            pl.BlockSpec((1,), lambda bh, iq, ik: (bh // Hq,)),
-            pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (kv_head(bh), ik, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda bh, iq, ik: (kv_head(bh), ik, 0)),
-            pl.BlockSpec((1, bq, Dv), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
+                          use_valid=use_valid, hq=Hq),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,      # kv_valid_len
+            grid=(B * Hq, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, bq, 1),
+                             lambda bh, iq, ik, vl: (bh // Hq, iq, 0)),
+                pl.BlockSpec((1, 1, bk),
+                             lambda bh, iq, ik, vl: (bh // Hq, 0, ik)),
+                pl.BlockSpec((1, bq, D), lambda bh, iq, ik, vl: (bh, iq, 0)),
+                pl.BlockSpec((1, bk, D),
+                             lambda bh, iq, ik, vl: (kv_head(bh), ik, 0)),
+                pl.BlockSpec((1, bk, Dv),
+                             lambda bh, iq, ik, vl: (kv_head(bh), ik, 0)),
+                pl.BlockSpec((1, bq, Dv), lambda bh, iq, ik, vl: (bh, iq, 0)),
+                pl.BlockSpec((1, bq, 1), lambda bh, iq, ik, vl: (bh, iq, 0)),
+                pl.BlockSpec((1, bq, 1), lambda bh, iq, ik, vl: (bh, iq, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, D),
+                                   lambda bh, iq, ik, vl: (bh, iq, 0)),
+            scratch_shapes=[pl_scratch((bq, D))],
+        ),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Tq_p, D), q.dtype),
-        scratch_shapes=[pl_scratch((bq, D))],
         interpret=interpret,
-    )(q_positions, kv_positions, kv_valid_len, qr, kr, vr, dor, lser, dsr)
+    )(valid, qpos, kvpos, qr, kr, vr, dor, lser, dsr)
 
     # ---- dk/dv: q laid out per-kv-head [B*Hkv, G, Tq, D]
     q5 = qr.reshape(B, Hq, Tq_p, D).reshape(B, Hkv, G, Tq_p, D) \
         .reshape(B * Hkv, G, Tq_p, D)
     do5 = dor.reshape(B, Hkv, G, Tq_p, Dv).reshape(B * Hkv, G, Tq_p, Dv)
-    lse5 = lser.reshape(B, Hkv, G, Tq_p).reshape(B * Hkv, G, Tq_p)
-    ds5 = dsr.reshape(B, Hkv, G, Tq_p).reshape(B * Hkv, G, Tq_p)
+    lse5 = lser.reshape(B * Hkv, G, Tq_p, 1)
+    ds5 = dsr.reshape(B * Hkv, G, Tq_p, 1)
     n_qg = n_q * G
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=scale, causal=causal,
                           window=window, softcap=softcap, n_q_steps=n_qg,
-                          use_valid=use_valid),
-        grid=(B * Hkv, n_k, n_qg),
-        in_specs=[
-            pl.BlockSpec((1, bq),
-                         lambda bh, ik, iqg, n=n_q: (bh // Hkv, iqg % n)),
-            pl.BlockSpec((1, bk), lambda bh, ik, iqg: (bh // Hkv, ik)),
-            pl.BlockSpec((1,), lambda bh, ik, iqg: (bh // Hkv,)),
-            pl.BlockSpec((1, 1, bq, D),
-                         lambda bh, ik, iqg, n=n_q: (bh, iqg // n,
-                                                     iqg % n, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ik, iqg: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda bh, ik, iqg: (bh, ik, 0)),
-            pl.BlockSpec((1, 1, bq, Dv),
-                         lambda bh, ik, iqg, n=n_q: (bh, iqg // n,
-                                                     iqg % n, 0)),
-            pl.BlockSpec((1, 1, bq),
-                         lambda bh, ik, iqg, n=n_q: (bh, iqg // n, iqg % n)),
-            pl.BlockSpec((1, 1, bq),
-                         lambda bh, ik, iqg, n=n_q: (bh, iqg // n, iqg % n)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda bh, ik, iqg: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda bh, ik, iqg: (bh, ik, 0)),
-        ],
+                          use_valid=use_valid, hkv=Hkv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,      # kv_valid_len
+            grid=(B * Hkv, n_k, n_qg),
+            in_specs=[
+                pl.BlockSpec((1, bq, 1),
+                             lambda bh, ik, iqg, vl, n=n_q: (bh // Hkv,
+                                                             iqg % n, 0)),
+                pl.BlockSpec((1, 1, bk),
+                             lambda bh, ik, iqg, vl: (bh // Hkv, 0, ik)),
+                pl.BlockSpec((1, 1, bq, D),
+                             lambda bh, ik, iqg, vl, n=n_q: (bh, iqg // n,
+                                                             iqg % n, 0)),
+                pl.BlockSpec((1, bk, D), lambda bh, ik, iqg, vl: (bh, ik, 0)),
+                pl.BlockSpec((1, bk, Dv),
+                             lambda bh, ik, iqg, vl: (bh, ik, 0)),
+                pl.BlockSpec((1, 1, bq, Dv),
+                             lambda bh, ik, iqg, vl, n=n_q: (bh, iqg // n,
+                                                             iqg % n, 0)),
+                pl.BlockSpec((1, 1, bq, 1),
+                             lambda bh, ik, iqg, vl, n=n_q: (bh, iqg // n,
+                                                             iqg % n, 0)),
+                pl.BlockSpec((1, 1, bq, 1),
+                             lambda bh, ik, iqg, vl, n=n_q: (bh, iqg // n,
+                                                             iqg % n, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bk, D), lambda bh, ik, iqg, vl: (bh, ik, 0)),
+                pl.BlockSpec((1, bk, Dv),
+                             lambda bh, ik, iqg, vl: (bh, ik, 0)),
+            ],
+            scratch_shapes=[pl_scratch((bk, D)), pl_scratch((bk, Dv))],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, Tk_p, D), k.dtype),
             jax.ShapeDtypeStruct((B * Hkv, Tk_p, Dv), v.dtype),
         ],
-        scratch_shapes=[pl_scratch((bk, D)), pl_scratch((bk, Dv))],
         interpret=interpret,
-    )(q_positions, kv_positions, kv_valid_len, q5, kr, vr, do5, lse5, ds5)
+    )(valid, qpos, kvpos, q5, kr, vr, do5, lse5, ds5)
 
     dq = dq.reshape(B, Hq, Tq_p, D).transpose(0, 2, 1, 3)[:, :Tq]
     dk = dk.reshape(B, Hkv, Tk_p, D).transpose(0, 2, 1, 3)[:, :Tk]

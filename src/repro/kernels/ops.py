@@ -1,15 +1,18 @@
 """Dispatching wrappers around the Pallas kernels.
 
-Every op has three implementations:
+Every op has these implementations:
   * ``pallas``   — the TPU kernel (``pl.pallas_call`` with VMEM BlockSpecs);
   * ``interpret``— the same kernel body executed in interpret mode (CPU
                    correctness validation);
-  * ``ref``      — the pure-jnp oracle in ``ref.py``.
+  * ``ref``      — the pure-jnp oracle in ``ref.py``;
+  * ``blocked``  — flash attention only: the jnp blocked-softmax path
+                   (``blocked_attention.py``); every other op maps it to
+                   ``ref``.
 
-Dispatch default: TPU backend → pallas, anything else → ref.  The dry-run
-intentionally uses the ref path so ``cost_analysis`` sees XLA einsum FLOPs.
-Force a path globally with ``set_impl("interpret")`` or per-call with
-``impl=...``.
+Dispatch default: TPU backend → pallas, anything else → blocked.  The
+dry-run intentionally uses the ref path so ``cost_analysis`` sees XLA
+einsum FLOPs.  Force a path globally with ``set_impl("interpret")`` or
+per-call with ``impl=...``.
 """
 from __future__ import annotations
 

@@ -51,7 +51,7 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     if ks_ref is not None:
         # int8 pool: fold the per-token dequant scale into the logits
         # (q·(k·s) == (q·k)·s) instead of dequantizing the tile
-        s = s * ks_ref[0, 0][None, :]
+        s = s * ks_ref[0, 0]                             # [1, page]
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
@@ -68,7 +68,7 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     if vs_ref is not None:
-        p = p * vs_ref[0, 0][None, :]                    # p·(v·s) == (p·s)·v
+        p = p * vs_ref[0, 0]                             # p·(v·s) == (p·s)·v
     v = v_ref[0, 0].astype(jnp.float32)                  # [page, Dv]
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(p, v)
     m_ref[...] = m_new
@@ -124,13 +124,14 @@ def paged_decode_attention(
     ]
     inputs = [qr, kr, vr]
     if k_scale is not None:
-        sc_spec = pl.BlockSpec((1, 1, page),
+        sc_spec = pl.BlockSpec((1, 1, 1, page),
                                lambda bh, ip, pt, cl: (pt[bh // Hkv, ip],
-                                                       bh % Hkv, 0))
+                                                       bh % Hkv, 0, 0))
         in_specs += [sc_spec, sc_spec]
-        # [P, Hkv, page] to match the transposed pool tiles
-        inputs += [k_scale.transpose(0, 2, 1).astype(jnp.float32),
-                   v_scale.transpose(0, 2, 1).astype(jnp.float32)]
+        # [P, Hkv, 1, page]: one lane-major scale row per (page, head)
+        # tile — the unit axis keeps the block's last two dims whole
+        inputs += [k_scale.transpose(0, 2, 1)[:, :, None].astype(jnp.float32),
+                   v_scale.transpose(0, 2, 1)[:, :, None].astype(jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # page_table, cache_len

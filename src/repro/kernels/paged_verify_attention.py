@@ -54,7 +54,7 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     k = k_ref[0, 0].astype(jnp.float32)                  # [page, D]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [K1*G, page]
     if ks_ref is not None:
-        s = s * ks_ref[0, 0][None, :]
+        s = s * ks_ref[0, 0]                             # [1, page]
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
@@ -73,7 +73,7 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     if vs_ref is not None:
-        p = p * vs_ref[0, 0][None, :]
+        p = p * vs_ref[0, 0]
     v = v_ref[0, 0].astype(jnp.float32)                  # [page, Dv]
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(p, v)
     m_ref[...] = m_new
@@ -134,13 +134,14 @@ def paged_verify_attention(
     ]
     inputs = [qr, kr, vr]
     if k_scale is not None:
-        sc_spec = pl.BlockSpec((1, 1, page),
+        sc_spec = pl.BlockSpec((1, 1, 1, page),
                                lambda bh, ip, pt, cl: (pt[bh // Hkv, ip],
-                                                       bh % Hkv, 0))
+                                                       bh % Hkv, 0, 0))
         in_specs += [sc_spec, sc_spec]
-        # [P, Hkv, page] to match the transposed pool tiles
-        inputs += [k_scale.transpose(0, 2, 1).astype(jnp.float32),
-                   v_scale.transpose(0, 2, 1).astype(jnp.float32)]
+        # [P, Hkv, 1, page]: one lane-major scale row per (page, head)
+        # tile — the unit axis keeps the block's last two dims whole
+        inputs += [k_scale.transpose(0, 2, 1)[:, :, None].astype(jnp.float32),
+                   v_scale.transpose(0, 2, 1)[:, :, None].astype(jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # page_table, cache_len

@@ -37,6 +37,9 @@ def main() -> None:
                     help="persist applied specs + quotas to this path")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    print(f"compile cache: {compile_cache.enable()}")
+
     from repro.configs import get_config, get_reduced_config
     from repro.core import (EdgeSystem, ExecutorClass, QoSClass, ServiceSpec,
                             Workload, WorkloadClass, WorkloadKind)

@@ -159,6 +159,17 @@ class RequestHandle:
         return self._engine._drive(self._req, timeout)
 
 
+def _mesh_device(mesh):
+    """The one device an engine on ``mesh`` runs on (``None``: default)."""
+    if mesh is None:
+        return None
+    devices = list(mesh.devices.flat)
+    if len(devices) != 1:
+        raise ValueError(f"a ServingEngine runs on one device; got a "
+                         f"{len(devices)}-device mesh")
+    return devices[0]
+
+
 def _buckets(max_seq: int) -> List[int]:
     out, b = [], 16
     while b < max_seq:
@@ -185,9 +196,15 @@ class ServingEngine:
         self.cfg = cfg
         self.replica_id = replica_id     # fleet membership tag ("" = solo)
         self.model = build_model(cfg)
-        self.params = params if params is not None else self.model.init(
-            jax.random.key(seed))
+        # one engine, one device: a node's one-device mesh pins the engine
+        # to that chip (fleet replicas one per chip); without a mesh it
+        # runs on the default device.  Its state is committed there at the
+        # end of __init__, so every jitted tick runs on that chip.
         self.mesh = mesh
+        self.device = _mesh_device(mesh)
+        with jax.default_device(self.device):
+            self.params = params if params is not None else self.model.init(
+                jax.random.key(seed))
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.buckets = _buckets(max_seq)
@@ -327,6 +344,13 @@ class ServingEngine:
             if self._chunkable_stateful:
                 self._chunk = jax.jit(self._chunk_stateful_fn,
                                       donate_argnums=(1,))
+
+        if self.device is not None:
+            self.params = jax.device_put(self.params, self.device)
+            self.kv.commit_to(self.device)
+            self.last_tokens = jax.device_put(self.last_tokens, self.device)
+            if self._draft is not None:
+                self._draft.commit_to(self.device)
 
     # ------------------------------------------------------------------
     @property
@@ -577,6 +601,11 @@ class ServingEngine:
                 # requests itself; this is a last-resort guard, so back
                 # off rather than hot-spin if something still escapes
                 time.sleep(0.05)
+            # yield the interpreter between ticks: the lock is not fair,
+            # and without this the loop re-takes it before a woken
+            # submit()/start() caller runs, starving it for a whole
+            # request's worth of ticks
+            time.sleep(0)
 
     def drain(self, timeout: Optional[float] = None) -> List[Request]:
         """Block until the queue and active set are empty."""

@@ -122,6 +122,11 @@ class SlotKVCache:
     def utilization(self) -> float:
         return 1.0 - len(self.free_slots) / self.max_slots
 
+    def commit_to(self, device) -> None:
+        """Pin the cache arrays to ``device`` (one engine per chip)."""
+        self.caches, self.cache_len = jax.device_put(
+            (self.caches, self.cache_len), device)
+
     # ----------------------------------------------------- byte accounting
     def capacity_bytes(self) -> int:
         return self._capacity_bytes
@@ -198,6 +203,12 @@ class PagedKVCache:
 
     def page_utilization(self) -> float:
         return self.pages_in_use() / max(self.num_pages - 1, 1)
+
+    def commit_to(self, device) -> None:
+        """Pin the pools, table and lengths to ``device`` (one engine per
+        chip)."""
+        self.pools, self.page_table, self.cache_len = jax.device_put(
+            (self.pools, self.page_table, self.cache_len), device)
 
     # ----------------------------------------------------- byte accounting
     def capacity_bytes(self) -> int:

@@ -121,6 +121,11 @@ class DraftSpeculator:
             active, k=k)
         return drafts
 
+    def commit_to(self, device) -> None:
+        """Pin the draft's params and cache to the engine's device."""
+        self.params = jax.device_put(self.params, device)
+        self.kv.commit_to(device)
+
     def observe(self, new_len: jax.Array, active: jax.Array) -> None:
         """Adopt the target's post-commit lengths (rewind past rejects)."""
         self.kv.cache_len = jnp.where(active, new_len, self.kv.cache_len)

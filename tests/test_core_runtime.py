@@ -213,3 +213,27 @@ def test_manager_routes_heavy_and_light_end_to_end():
     assert "container" in res2.executor_name
     rep = mgr.report()
     assert rep["heavy"]["count"] == 1 and rep["light"]["count"] == 1
+
+
+# ---------------------------------------------------------------- capacity
+def test_node_capacity_reads_accelerator_devices():
+    from types import SimpleNamespace
+
+    from repro.core.resources import PEAK_FLOPS_BY_KIND
+
+    chip = SimpleNamespace(device_kind="TPU v5 lite",
+                           memory_stats=lambda: {"bytes_limit": 1000})
+    cap = NodeCapacity.of_devices([chip, chip])
+    assert (cap.chips, cap.hbm_bytes) == (2, 2000)
+    assert cap.flops_per_s == 2 * PEAK_FLOPS_BY_KIND["TPU v5 lite"]
+
+
+def test_node_capacity_refuses_device_without_known_peak():
+    """The CPU backend (and any unlisted kind) has no capacity to read: a
+    node there must be given one explicitly, never a v5e default."""
+    from repro.core import EdgeSystem
+
+    with pytest.raises(ValueError, match="explicit NodeCapacity"):
+        NodeCapacity.of_devices(jax.devices()[:1])
+    with pytest.raises(ValueError, match="explicit NodeCapacity"):
+        EdgeSystem().add_node("n0")

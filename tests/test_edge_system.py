@@ -269,6 +269,6 @@ def test_spec_validation_and_unknown_builder():
                     workload=Workload("w", WorkloadKind.GENERIC),
                     replicas=-1)
     system = EdgeSystem()
-    system.add_node("n0")
+    system.add_node("n0", NodeCapacity(hbm_bytes=16 << 30))
     with pytest.raises(PlacementError):
         system.apply(_spec())                # no builder registered

@@ -240,7 +240,8 @@ def test_submit_many_mixed_batch_overlaps_engine_ticks(tiny_cfg):
 
     def build_system():
         system = EdgeSystem()
-        system.add_node("edge0").add_node("edge1")
+        node = NodeCapacity(hbm_bytes=16 << 30)
+        system.add_node("edge0", node).add_node("edge1", node)
         system.register_builder(
             "decode", WorkloadClass.HEAVY,
             make_engine_builder(tiny_cfg, max_slots=4, max_seq=64))

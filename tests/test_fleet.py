@@ -319,3 +319,65 @@ def test_deploy_fleet_admission_and_failover(fleet_cfg):
         assert req2.done and len(req2.generated) == 3
     finally:
         router.shutdown()
+
+
+def test_fleet_replicas_pinned_one_per_device():
+    """Four one-device nodes (four virtual CPU devices, in a subprocess):
+    ``deploy_fleet`` puts each replica's params and KV pools on its own
+    node's device, the routed fleet's tokens equal one replica's, and an
+    engine refuses a multi-device mesh it cannot shard over."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    code = textwrap.dedent("""
+        import jax, numpy as np
+        from jax.sharding import Mesh
+        from repro.configs import get_reduced_config
+        from repro.core import EdgeSystem, NodeCapacity, WorkloadClass
+        from repro.serving.engine import ServingEngine
+        from repro.serving.router import (fleet_service_spec,
+                                          make_fleet_builder)
+
+        cfg = get_reduced_config("tinyllama-1.1b")
+        devs = jax.devices()
+        system = EdgeSystem()
+        for i, d in enumerate(devs):
+            system.add_node(f"chip{i}", NodeCapacity(hbm_bytes=1 << 34),
+                            mesh=Mesh(np.array([d]), ("replica",)))
+        system.register_builder("generic", WorkloadClass.HEAVY,
+                                make_fleet_builder(cfg, max_slots=2,
+                                                   max_seq=64))
+        router = system.deploy_fleet(fleet_service_spec(
+            cfg, name="f", replicas=4, max_new_tokens=4))
+        engines = [d.executor.engine for d in system.instances("f")]
+        held = [{d for x in jax.tree.leaves((e.params, e.kv.pools))
+                 for d in x.devices()} for e in engines]
+        assert held == [{d} for d in devs], held
+        rng = np.random.default_rng(0)
+        prompts = [rng.integers(0, cfg.vocab_size, size=n)
+                   for n in (5, 9, 17, 30)]
+        solo = [engines[0].submit(p, max_new_tokens=4).result(timeout=300)
+                for p in prompts]
+        engines[0].release_prefix_cache()
+        fleet = [router.submit(p, max_new_tokens=4) for p in prompts]
+        fleet = [h.result(timeout=300) for h in fleet]
+        assert [r.generated for r in fleet] == [r.generated for r in solo]
+        assert all(not e.failed for e in engines)
+        router.shutdown()
+        try:
+            ServingEngine(cfg, max_slots=1, max_seq=32,
+                          mesh=Mesh(np.array(devs[:2]), ("x",)))
+        except ValueError as e:
+            print("refused:", e)
+        print("placed", len(held))
+    """)
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    assert "placed 4" in r.stdout and "refused: a ServingEngine" in r.stdout

@@ -237,15 +237,16 @@ def test_spec_counters_reach_system_stats(exact_config):
     private one — the manager merges executor ``stats_extras()`` on
     every recorded dispatch."""
     from benchmarks.common import stats_suffix
-    from repro.core import (EdgeSystem, ExecutorClass, ServiceSpec,
-                            Workload, WorkloadClass, WorkloadKind)
+    from repro.core import (EdgeSystem, ExecutorClass, NodeCapacity,
+                            ServiceSpec, Workload, WorkloadClass,
+                            WorkloadKind)
     from repro.serving.router import make_engine_builder
 
     cfg = exact_config("tinyllama-1.1b")
     dcfg = exact_config("tinyllama-1.1b", num_layers=1, num_heads=1,
                         num_kv_heads=1, d_ff=32)
     system = EdgeSystem()
-    system.add_node("edge0")
+    system.add_node("edge0", NodeCapacity(hbm_bytes=16 << 30))
     system.register_builder(
         "decode", WorkloadClass.HEAVY,
         make_engine_builder(cfg, max_slots=2, max_seq=64, autostart=False,

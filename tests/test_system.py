@@ -8,6 +8,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_reduced_config
 from repro.core import (EdgeSystem, ExecutorClass, LeastLoadedPolicy,
@@ -110,3 +111,19 @@ def test_elastic_scale_with_load():
         system.queue.get()
     n = system.autoscale("svc", per_instance=4, min_n=1)
     assert n == 1                                   # scaled down: saves power
+
+
+@pytest.mark.parametrize("env_dir", [None, "/var/cache/jax-example"])
+def test_compile_cache_dir_prefers_environment(monkeypatch, env_dir):
+    """Entry points cache compiles where JAX_COMPILATION_CACHE_DIR says,
+    else at the fixed <repo>/.jax_cache — never a per-run path."""
+    from repro.launch import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        want = str(compile_cache.REPO_ROOT / ".jax_cache")
+        assert (compile_cache.REPO_ROOT / "chip_smoke.py").exists()
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+        want = env_dir
+    assert compile_cache.cache_dir() == want
