@@ -5,6 +5,9 @@ typed sample stream and percentile summaries (p50/p95/p99 wall, cold vs
 warm split, per-class footprints).  The benchmarks and ``launch/serve.py``
 consume the same summaries the manager's ``report()`` exposes, so every
 layer reports latency the same way.
+
+``span`` is the one tracing primitive: a host span in the JAX profiler's
+own trace, on the device ops' clock.
 """
 from __future__ import annotations
 
@@ -12,7 +15,17 @@ import dataclasses
 import threading
 from typing import Dict, List, Optional, Sequence
 
+from jax.profiler import TraceAnnotation
+
 PERCENTILES = (50.0, 95.0, 99.0)
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """Context manager: a host span ``name`` with ``args`` as its event
+    stats.  While the profiler traces, it lands on ``/host:CPU`` of the
+    same ``.xplane.pb`` as the device ops and is written at
+    ``stop_trace``; otherwise it records nothing (about a microsecond)."""
+    return TraceAnnotation(name, **args)
 
 
 def percentile(samples: Sequence[float], q: float) -> float:

@@ -115,5 +115,6 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dv), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(cache_len.astype(jnp.int32), qr, kr, vr)
     return out.reshape(B, Hq, Dv)

@@ -172,6 +172,7 @@ def flash_attention(  # analysis: oracle=mha
             jax.ShapeDtypeStruct((B * Hq, Tq_p, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(kv_valid_len.astype(jnp.int32), q_positions[:, :, None],
       kv_positions[:, None, :], qr, kr, vr)
 

@@ -230,6 +230,7 @@ def flash_attention_bwd(
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Tq_p, D), q.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(valid, qpos, kvpos, qr, kr, vr, dor, lser, dsr)
 
     # ---- dk/dv: q laid out per-kv-head [B*Hkv, G, Tq, D]
@@ -281,6 +282,7 @@ def flash_attention_bwd(
             jax.ShapeDtypeStruct((B * Hkv, Tk_p, Dv), v.dtype),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(valid, qpos, kvpos, q5, kr, vr, do5, lse5, ds5)
 
     dq = dq.reshape(B, Hq, Tq_p, D).transpose(0, 2, 1, 3)[:, :Tq]

@@ -147,5 +147,6 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dv), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table.astype(jnp.int32), cache_len.astype(jnp.int32), *inputs)
     return out.reshape(B, Hq, Dv)

@@ -157,6 +157,7 @@ def paged_verify_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, R, Dv), q.dtype),
         interpret=interpret,
+        name="paged_verify_attention",
     )(page_table.astype(jnp.int32), cache_len.astype(jnp.int32), *inputs)
     return (out.reshape(B, Hkv, K1, G, Dv).transpose(0, 2, 1, 3, 4)
             .reshape(B, K1, Hq, Dv))

@@ -48,5 +48,6 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xr, scale)
     return out[:rows].reshape(orig_shape)

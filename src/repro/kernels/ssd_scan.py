@@ -137,6 +137,7 @@ def ssd_scan(
         ],
         scratch_shapes=[pl_scratch((N, P))],
         interpret=interpret,
+        name="ssd_scan",
     )(xr, dtr, A, br, cr, s0)
 
     y = y.reshape(Bb, H, T, P).transpose(0, 2, 1, 3)[:, :T0]

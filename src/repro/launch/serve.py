@@ -112,12 +112,6 @@ def main() -> None:
               f"pool {stats['kv_capacity_bytes'] / 2**20:.1f}MiB, "
               f"peak in-tick budget "
               f"{stats.get('max_prefill_tokens_tick', 0)} prefill tok")
-    summary = engine.dispatch_stats.summary()["heavy"]
-    if summary:
-        print(f"  dispatch_stats: count={summary['count']} "
-              f"p50={summary['p50_wall_s'] * 1e3:.1f}ms "
-              f"p95={summary['p95_wall_s'] * 1e3:.1f}ms "
-              f"p99={summary['p99_wall_s'] * 1e3:.1f}ms")
 
     if args.slo_ms:
         slo_reqs = [r for r in done if r.latency_slo_ms > 0]

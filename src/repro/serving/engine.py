@@ -48,6 +48,16 @@ prompts raise ``ValueError`` immediately); anything that fails *inside*
 the loop marks the request failed and surfaces the error through its
 future instead of crashing the loop thread.
 
+Tracing: each phase of the loop thread runs under one flat host span
+(``core.telemetry.span``, args ``tick`` and, per request, ``rid``):
+``engine.wait`` between ticks, ``engine.admit``, one ``engine.prefill``
+per chunk, ``engine.pages`` (decode-page growth), ``engine.decode`` (the
+dispatch), ``engine.sync`` (each blocking device-to-host read) and
+``engine.finish`` (bookkeeping after the sync); ``engine.submit`` marks
+an arrival on the caller's thread.  Under the profiler they share the
+device ops' clock, so device idle can be put down to a phase.
+``Request.token_times`` holds when each generated token was committed.
+
 SLO-aware admission: requests carry ``latency_slo_ms``; both the
 admission pass and the per-tick chunk scheduler order by remaining SLO
 slack (``slo_slack``), so tight-SLO requests jump ahead of slack FIFO
@@ -71,7 +81,7 @@ import numpy as np
 
 from repro.core.executor import (BaseExecutor, DispatchRecord,
                                  ExecutorClass)
-from repro.core.telemetry import DispatchSample, DispatchStats, percentile
+from repro.core.telemetry import DispatchStats, percentile, span
 from repro.core.workload import Workload, WorkloadKind
 from repro.models.config import ModelConfig
 from repro.models.model import build_model
@@ -102,6 +112,10 @@ class Request:
     staging: Any = None                # batch-1 resume cache (stateful chunk)
     table_row: Any = None              # [1, MP] page-table row (paged)
     generated: List[int] = dataclasses.field(default_factory=list)
+    # monotonic time each generated token was committed (tokens of one
+    # tick share it): token_times[0] is first_token_at and, once finished,
+    # token_times[-1] is finished_at
+    token_times: List[float] = dataclasses.field(default_factory=list)
     done: bool = False
     error: Optional[str] = None
     admitted_at: Optional[float] = None
@@ -279,6 +293,8 @@ class ServingEngine:
         self.last_tokens = jnp.zeros((max_slots,), jnp.int32)
         self._rid = itertools.count()
         self.ticks = 0
+        self.decode_tokens_committed = 0
+        self.max_prefill_tokens_tick = 0
         self.dispatch_stats = DispatchStats()
         # fleet routing surfaces: recent queue waits (admission-time) for
         # fleet-aggregate p95 autoscale, prefix-affinity hit counters
@@ -436,7 +452,10 @@ class ServingEngine:
             if self._warm:
                 return self
             t0 = time.monotonic()
-            zero1 = jnp.zeros((1,), jnp.int32)
+            # built as the chunk path builds its positions, so the eager
+            # conversion program of ``jnp.asarray([int], jnp.int32)`` is
+            # compiled here too
+            zero1 = jnp.asarray([0], jnp.int32)
             if self.paged:
                 row = jnp.zeros((1, self.kv.pages_per_slot), jnp.int32)
                 logits = None
@@ -453,12 +472,17 @@ class ServingEngine:
                             row[:, :kv_pages], zero1, zero1)
                         self.kv.pools = pools
                 # absorb the first-token host programs (argmax, table/len
-                # scatters) — all no-ops on an idle engine's zero state
+                # scatters) and the eager page-table updates of decode-page
+                # growth (``kv.append_page``: a Python-int entry) and of
+                # release (``kv.free``: a row set to 0) — all no-ops on an
+                # idle engine's zero state
                 if logits is not None and not self.active and not self.queue:
                     int(np.asarray(jnp.argmax(logits, -1))[0])
                     self.last_tokens = self.last_tokens.at[0].set(
                         jnp.asarray(0, jnp.int32))
                     self.kv.install(0, row, 0)
+                    self.kv.page_table = self.kv.page_table.at[0, 0].set(0)
+                    self.kv.page_table = self.kv.page_table.at[0].set(0)
                 toks, pools, clen = self._decode(
                     self.params, self.kv.pools, self.kv.page_table,
                     self.last_tokens, self.kv.cache_len,
@@ -589,23 +613,27 @@ class ServingEngine:
         self.stop(drain=exc[0] is None)
 
     def _loop(self):
+        backoff = 0.0
         while True:
-            with self._lock:
-                while self._running and not self.queue and not self.active:
-                    self._work.wait(timeout=0.5)
-                if not self._running:
-                    return
+            with span("engine.wait", tick=self.ticks):  # analysis: unguarded-ok — racy int read for a trace label
+                # yield the interpreter between ticks: the lock is not
+                # fair, and without this the loop re-takes it before a
+                # woken submit()/start() caller runs, starving it for a
+                # whole request's worth of ticks
+                time.sleep(backoff)
+                with self._lock:
+                    while self._running and not self.queue \
+                            and not self.active:
+                        self._work.wait(timeout=0.5)
+                    if not self._running:
+                        return
             try:
                 self.step()
+                backoff = 0.0
             except Exception:  # noqa: BLE001 — step fails the offending
                 # requests itself; this is a last-resort guard, so back
                 # off rather than hot-spin if something still escapes
-                time.sleep(0.05)
-            # yield the interpreter between ticks: the lock is not fair,
-            # and without this the loop re-takes it before a woken
-            # submit()/start() caller runs, starving it for a whole
-            # request's worth of ticks
-            time.sleep(0)
+                backoff = 0.05
 
     def drain(self, timeout: Optional[float] = None) -> List[Request]:
         """Block until the queue and active set are empty."""
@@ -666,12 +694,14 @@ class ServingEngine:
         if qos not in _QOS_RANK:
             raise ValueError(f"unknown qos {qos!r}; "
                              f"expected one of {sorted(_QOS_RANK)}")
-        req = Request(next(self._rid), prompt,
-                      max_new_tokens, eos_token, latency_slo_ms, qos,
-                      submitted_at=time.monotonic(), future=Future())
-        with self._lock:
-            self.queue.append(req)
-            self._work.notify_all()
+        rid = next(self._rid)
+        with span("engine.submit", rid=rid):
+            req = Request(rid, prompt, max_new_tokens, eos_token,
+                          latency_slo_ms, qos, submitted_at=time.monotonic(),
+                          future=Future())
+            with self._lock:
+                self.queue.append(req)
+                self._work.notify_all()
         return RequestHandle(self, req)
 
     # -------------------------------------------------- fleet probe surface
@@ -923,86 +953,94 @@ class ServingEngine:
         plen = len(req.prompt)
         bucket, real = self._chunk_plan(req)
         start = req.pos
-        try:
-            if self.paged:
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :real] = req.prompt[start:start + real]
-                # gather only a pow2-bucketed prefix of the page table:
-                # early chunks attend tens of tokens, not max_seq — the
-                # sliced row's width keys the (chunk, span) compile
-                kv_pages = self._kv_span_pages(start + real)
-                logits, pools = self._chunk(
-                    self.params, self.kv.pools, jnp.asarray(padded),
-                    req.table_row[:, :kv_pages],
-                    jnp.asarray([start], jnp.int32),
-                    jnp.asarray([start + real], jnp.int32))
-                self.kv.pools = pools
-            elif self._chunkable_stateful:
-                toks = jnp.asarray(req.prompt[None, start:start + real],
-                                   jnp.int32)
-                logits, req.staging = self._chunk(
-                    self.params, req.staging, toks,
-                    jnp.asarray([start], jnp.int32),
-                    jnp.asarray([start + real], jnp.int32))
-            else:
-                # monolithic: exact length for stateful archs, pow2 bucket
-                # (with last_index masking) for full attention
-                bucket = plen if self._stateful else next(
-                    b for b in self.buckets if b >= plen)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :plen] = req.prompt
-                logits, pcache, _ = self._prefill(
-                    self.params, jnp.asarray(padded),
-                    jnp.asarray([plen - 1], jnp.int32), bucket=bucket)
-                real = plen
-        except Exception as e:  # noqa: BLE001
-            if self.paged:
-                # the chunk donates the SHARED pools: a runtime failure
-                # leaves every admitted request's cache state suspect, so
-                # fail the batch (mirrors the decode error path) instead
-                # of ticking on with poisoned pools
-                for other in list(self.active.values()):
-                    self._release(other)
-                    del self.active[other.rid]
-                    self._fail(other, e)
-            else:
-                # stateful chunks donate only the request's own staging
-                self._release(req)
-                del self.active[req.rid]
-                self._fail(req, e)
-            return 0
-        req.pos += real
-        req.chunks += 1
-        if req.pos < plen:
-            return real
-        # ---- prompt complete: publish the cache and enter decode -------
-        first = int(np.asarray(jnp.argmax(logits, -1))[0])
-        if self.paged:
-            self.kv.install(req.slot, req.table_row, plen)
-        elif self._chunkable_stateful:
-            self.kv.insert(req.staging, req.slot, plen)
-            req.staging = None
-        else:
-            self.kv.insert(pcache, req.slot, plen)
-        self.last_tokens = self.last_tokens.at[req.slot].set(first)
-        if self._draft is not None and req.max_new_tokens > 1:
-            # mirror the prompt into the draft's slot cache so the first
-            # speculative tick starts in sync (draft clen == target clen,
-            # same pending token).  A draft-side failure never fails the
-            # request — speculation just turns itself off.
+        with span("engine.prefill", tick=self.ticks, rid=req.rid,
+                  start=start, tokens=real, bucket=bucket):
             try:
-                self._draft.prefill(req.prompt, req.slot)
-            except Exception as e:  # noqa: BLE001 — draft state is its own
-                # tree; the target's pools are untouched
-                self._draft = None
-                self._spec_disabled_reason = f"draft prefill: {e}"
-        req.generated.append(first)
-        now = time.monotonic()
-        req.first_token_at = now
-        req.phase = "decode"
-        if (req.eos_token is not None and first == req.eos_token) or \
-                req.max_new_tokens <= 1:
-            self._finish(req, now)
+                if self.paged:
+                    padded = np.zeros((1, bucket), np.int32)
+                    padded[0, :real] = req.prompt[start:start + real]
+                    # gather only a pow2-bucketed prefix of the page
+                    # table: early chunks attend tens of tokens, not
+                    # max_seq — the sliced row's width keys the (chunk,
+                    # span) compile
+                    kv_pages = self._kv_span_pages(start + real)
+                    logits, pools = self._chunk(
+                        self.params, self.kv.pools, jnp.asarray(padded),
+                        req.table_row[:, :kv_pages],
+                        jnp.asarray([start], jnp.int32),
+                        jnp.asarray([start + real], jnp.int32))
+                    self.kv.pools = pools
+                elif self._chunkable_stateful:
+                    toks = jnp.asarray(req.prompt[None, start:start + real],
+                                       jnp.int32)
+                    logits, req.staging = self._chunk(
+                        self.params, req.staging, toks,
+                        jnp.asarray([start], jnp.int32),
+                        jnp.asarray([start + real], jnp.int32))
+                else:
+                    # monolithic: exact length for stateful archs, pow2
+                    # bucket (with last_index masking) for full attention
+                    bucket = plen if self._stateful else next(
+                        b for b in self.buckets if b >= plen)
+                    padded = np.zeros((1, bucket), np.int32)
+                    padded[0, :plen] = req.prompt
+                    logits, pcache, _ = self._prefill(
+                        self.params, jnp.asarray(padded),
+                        jnp.asarray([plen - 1], jnp.int32), bucket=bucket)
+                    real = plen
+            except Exception as e:  # noqa: BLE001
+                if self.paged:
+                    # the chunk donates the SHARED pools: a runtime
+                    # failure leaves every admitted request's cache state
+                    # suspect, so fail the batch (mirrors the decode error
+                    # path) instead of ticking on with poisoned pools
+                    for other in list(self.active.values()):
+                        self._release(other)
+                        del self.active[other.rid]
+                        self._fail(other, e)
+                else:
+                    # stateful chunks donate only the request's own staging
+                    self._release(req)
+                    del self.active[req.rid]
+                    self._fail(req, e)
+                return 0
+            req.pos += real
+            req.chunks += 1
+            if req.pos < plen:
+                return real
+        # ---- prompt complete: publish the cache and enter decode -------
+        with span("engine.sync", tick=self.ticks, rid=req.rid,
+                  what="first_token"):
+            first = int(np.asarray(jnp.argmax(logits, -1))[0])
+        with span("engine.finish", tick=self.ticks, rid=req.rid):
+            if self.paged:
+                self.kv.install(req.slot, req.table_row, plen)
+            elif self._chunkable_stateful:
+                self.kv.insert(req.staging, req.slot, plen)
+                req.staging = None
+            else:
+                self.kv.insert(pcache, req.slot, plen)
+            self.last_tokens = self.last_tokens.at[req.slot].set(first)
+            if self._draft is not None and req.max_new_tokens > 1:
+                # mirror the prompt into the draft's slot cache so the
+                # first speculative tick starts in sync (draft clen ==
+                # target clen, same pending token).  A draft-side failure
+                # never fails the request — speculation just turns itself
+                # off.
+                try:
+                    self._draft.prefill(req.prompt, req.slot)
+                except Exception as e:  # noqa: BLE001 — draft state is
+                    # its own tree; the target's pools are untouched
+                    self._draft = None
+                    self._spec_disabled_reason = f"draft prefill: {e}"
+            now = time.monotonic()
+            req.generated.append(first)
+            req.token_times.append(now)
+            req.first_token_at = now
+            req.phase = "decode"
+            if (req.eos_token is not None and first == req.eos_token) or \
+                    req.max_new_tokens <= 1:
+                self._finish(req, now)
         return real
 
     def _prefill_tick(self) -> int:
@@ -1050,6 +1088,7 @@ class ServingEngine:
         victim.pos = 0
         victim.chunks = 0
         victim.generated = []
+        victim.token_times = []
         victim.first_token_at = None
         victim.admitted_at = None
         victim.kv_shared_tokens = 0
@@ -1154,76 +1193,85 @@ class ServingEngine:
         bulk.  Returns ``(rows, committed_tokens)``, or ``None`` when the
         draft died — speculation disables itself and the caller serves the
         batch with the normal tick instead."""
-        stalled = self._grow_decode_pages(dec, span=k + 1)
-        dec = [r for r in dec if r.rid in self.active
-               and r.phase == "decode" and r.rid not in stalled]
+        with span("engine.pages", tick=self.ticks):
+            stalled = self._grow_decode_pages(dec, span=k + 1)
+            dec = [r for r in dec if r.rid in self.active
+                   and r.phase == "decode" and r.rid not in stalled]
         if not dec:
             return 0, 0
-        active_mask = np.zeros((self.max_slots,), bool)
-        for req in dec:
-            active_mask[req.slot] = True
-        active = jnp.asarray(active_mask)
-        try:
-            drafts = self._draft.propose(self.last_tokens, active, k)
-            self.draft_ticks += 1
-        except Exception as e:  # noqa: BLE001 — the draft donates only its
-            # own cache tree; the target's pools are untouched, so drop to
-            # non-speculative serving instead of failing the batch
-            self._draft = None
-            self._spec_disabled_reason = f"draft propose: {e}"
-            return None
-        tokens_blk = jnp.concatenate([self.last_tokens[:, None], drafts],
-                                     axis=1)
-        try:
-            tgt, acc, nxt, pools, new_len = self._verify(
-                self.params, self.kv.pools, self.kv.page_table, tokens_blk,
-                self.kv.cache_len, self.last_tokens, active)
-            self.kv.pools = pools
-            self.kv.cache_len = new_len
-        except Exception as e:  # noqa: BLE001 — verify donates the SHARED
-            # pools: same blast radius as the normal decode error path
-            for req in list(self.active.values()):
-                self._release(req)
-                del self.active[req.rid]
-                self._fail(req, e)
-            return 0, 0
-        self._draft.observe(new_len, active)
-        self.last_tokens = nxt
+        with span("engine.decode", tick=self.ticks, rows=len(dec), k=k):
+            active_mask = np.zeros((self.max_slots,), bool)
+            for req in dec:
+                active_mask[req.slot] = True
+            active = jnp.asarray(active_mask)
+            try:
+                drafts = self._draft.propose(self.last_tokens, active, k)
+                self.draft_ticks += 1
+            except Exception as e:  # noqa: BLE001 — the draft donates
+                # only its own cache tree; the target's pools are
+                # untouched, so drop to non-speculative serving instead
+                # of failing the batch
+                self._draft = None
+                self._spec_disabled_reason = f"draft propose: {e}"
+                return None
+            tokens_blk = jnp.concatenate(
+                [self.last_tokens[:, None], drafts], axis=1)
+            try:
+                tgt, acc, nxt, pools, new_len = self._verify(
+                    self.params, self.kv.pools, self.kv.page_table,
+                    tokens_blk, self.kv.cache_len, self.last_tokens,
+                    active)
+                self.kv.pools = pools
+                self.kv.cache_len = new_len
+            except Exception as e:  # noqa: BLE001 — verify donates the
+                # SHARED pools: same blast radius as the normal decode
+                # error path
+                for req in list(self.active.values()):
+                    self._release(req)
+                    del self.active[req.rid]
+                    self._fail(req, e)
+                return 0, 0
+            self._draft.observe(new_len, active)
+            self.last_tokens = nxt
         # ONE device sync per tick (not one per request)
-        tgt_np = np.asarray(tgt)
-        drafts_np = np.asarray(drafts)
-        accs = np.asarray(acc)
-        clens = np.asarray(self.kv.cache_len)
-        now = time.monotonic()
-        committed_total = 0
-        finished = []
-        for req in dec:
-            a = int(accs[req.slot])
-            committed = [int(x) for x in drafts_np[req.slot, :a]]
-            committed.append(int(tgt_np[req.slot, a]))
-            self.spec_proposed += k
-            self.spec_accepted += a
-            req.spec_ema = 0.7 * req.spec_ema + 0.3 * (a / k)
-            for t in committed:
-                req.generated.append(t)
-                committed_total += 1
-                if (req.eos_token is not None and t == req.eos_token) or \
-                        len(req.generated) >= req.max_new_tokens:
-                    finished.append(req)
-                    break
-            else:
-                if int(clens[req.slot]) >= self.kv.max_seq - 1:
-                    finished.append(req)
-        self.spec_rounds += 1
-        self.dispatch_stats.set_extra("speculation", {
-            "spec_proposed": self.spec_proposed,
-            "spec_accepted": self.spec_accepted,
-            "acceptance_rate": self.spec_accepted / self.spec_proposed
-            if self.spec_proposed else 0.0,
-            "draft_ticks": self.draft_ticks,
-        })
-        for req in finished:
-            self._finish(req, now)
+        with span("engine.sync", tick=self.ticks, what="verify"):
+            tgt_np = np.asarray(tgt)
+            drafts_np = np.asarray(drafts)
+            accs = np.asarray(acc)
+            clens = np.asarray(self.kv.cache_len)
+        with span("engine.finish", tick=self.ticks):
+            now = time.monotonic()
+            committed_total = 0
+            finished = []
+            for req in dec:
+                a = int(accs[req.slot])
+                committed = [int(x) for x in drafts_np[req.slot, :a]]
+                committed.append(int(tgt_np[req.slot, a]))
+                self.spec_proposed += k
+                self.spec_accepted += a
+                req.spec_ema = 0.7 * req.spec_ema + 0.3 * (a / k)
+                for t in committed:
+                    req.generated.append(t)
+                    req.token_times.append(now)
+                    committed_total += 1
+                    if (req.eos_token is not None
+                            and t == req.eos_token) or \
+                            len(req.generated) >= req.max_new_tokens:
+                        finished.append(req)
+                        break
+                else:
+                    if int(clens[req.slot]) >= self.kv.max_seq - 1:
+                        finished.append(req)
+            self.spec_rounds += 1
+            self.dispatch_stats.set_extra("speculation", {
+                "spec_proposed": self.spec_proposed,
+                "spec_accepted": self.spec_accepted,
+                "acceptance_rate": self.spec_accepted / self.spec_proposed
+                if self.spec_proposed else 0.0,
+                "draft_ticks": self.draft_ticks,
+            })
+            for req in finished:
+                self._finish(req, now)
         return len(dec), committed_total
 
     # ------------------------------------------------------- decode phase
@@ -1247,52 +1295,58 @@ class ServingEngine:
                 if not dec:
                     return 0, 0
         if self.paged:
-            stalled = self._grow_decode_pages(dec)
-            dec = [r for r in dec if r.rid in self.active
-                   and r.phase == "decode" and r.rid not in stalled]
+            with span("engine.pages", tick=self.ticks):
+                stalled = self._grow_decode_pages(dec)
+                dec = [r for r in dec if r.rid in self.active
+                       and r.phase == "decode" and r.rid not in stalled]
             if not dec:
                 return 0, 0
-        active_mask = np.zeros((self.max_slots,), bool)
-        for req in dec:
-            active_mask[req.slot] = True
-        try:
-            if self.paged:
-                tokens, pools, new_len = self._decode(
-                    self.params, self.kv.pools, self.kv.page_table,
-                    self.last_tokens, self.kv.cache_len,
-                    jnp.asarray(active_mask))
-                self.kv.pools = pools
-                self.kv.cache_len = new_len
-            else:
-                tokens, self.kv.caches, self.kv.cache_len = self._decode(
-                    self.params, self.kv.caches, self.last_tokens,
-                    self.kv.cache_len, jnp.asarray(active_mask))
-        except Exception as e:  # noqa: BLE001 — a decode error poisons
-            # the donated cache state for EVERY admitted request
-            # (prefilling rows share the pools): fail them all so blocked
-            # handles surface the error instead of hanging
-            for req in list(self.active.values()):
-                self._release(req)
-                del self.active[req.rid]
-                self._fail(req, e)
-            return 0, 0
-        self.last_tokens = tokens
-        toks = np.asarray(tokens)
+        with span("engine.decode", tick=self.ticks, rows=len(dec)):
+            active_mask = np.zeros((self.max_slots,), bool)
+            for req in dec:
+                active_mask[req.slot] = True
+            try:
+                if self.paged:
+                    tokens, pools, new_len = self._decode(
+                        self.params, self.kv.pools, self.kv.page_table,
+                        self.last_tokens, self.kv.cache_len,
+                        jnp.asarray(active_mask))
+                    self.kv.pools = pools
+                    self.kv.cache_len = new_len
+                else:
+                    tokens, self.kv.caches, self.kv.cache_len = \
+                        self._decode(self.params, self.kv.caches,
+                                     self.last_tokens, self.kv.cache_len,
+                                     jnp.asarray(active_mask))
+            except Exception as e:  # noqa: BLE001 — a decode error
+                # poisons the donated cache state for EVERY admitted
+                # request (prefilling rows share the pools): fail them all
+                # so blocked handles surface the error instead of hanging
+                for req in list(self.active.values()):
+                    self._release(req)
+                    del self.active[req.rid]
+                    self._fail(req, e)
+                return 0, 0
+            self.last_tokens = tokens
         # ONE device sync per tick (not one per request)
-        clens = np.asarray(self.kv.cache_len)
-        now = time.monotonic()
-        finished = []
-        for req in dec:
-            t = int(toks[req.slot])
-            req.generated.append(t)
-            if req.first_token_at is None:
-                req.first_token_at = now
-            if (req.eos_token is not None and t == req.eos_token) or \
-                    len(req.generated) >= req.max_new_tokens or \
-                    int(clens[req.slot]) >= self.kv.max_seq - 1:
-                finished.append(req)
-        for req in finished:
-            self._finish(req, now)
+        with span("engine.sync", tick=self.ticks, what="tokens+cache_len"):
+            toks = np.asarray(tokens)
+            clens = np.asarray(self.kv.cache_len)
+        with span("engine.finish", tick=self.ticks):
+            now = time.monotonic()
+            finished = []
+            for req in dec:
+                t = int(toks[req.slot])
+                req.generated.append(t)
+                req.token_times.append(now)
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                if (req.eos_token is not None and t == req.eos_token) or \
+                        len(req.generated) >= req.max_new_tokens or \
+                        int(clens[req.slot]) >= self.kv.max_seq - 1:
+                    finished.append(req)
+            for req in finished:
+                self._finish(req, now)
         return len(dec), len(dec)
 
     # ---------------------------------------------------------------- tick
@@ -1305,7 +1359,8 @@ class ServingEngine:
         caller-driven thread stepping.
         """
         with self._lock:
-            self._admit()
+            with span("engine.admit", tick=self.ticks):
+                self._admit()
             if not self.active:
                 self._tick.notify_all()
                 return 0
@@ -1316,6 +1371,9 @@ class ServingEngine:
             t2 = time.monotonic()
             if prefill_tokens or decode_rows:
                 self.ticks += 1
+                self.decode_tokens_committed += decode_tokens
+                self.max_prefill_tokens_tick = max(
+                    self.max_prefill_tokens_tick, prefill_tokens)
                 self._tick_log.append((t1 - t0, t2 - t1, prefill_tokens,
                                        decode_rows, decode_tokens))
             self._tick.notify_all()
@@ -1341,12 +1399,6 @@ class ServingEngine:
         self._release(req)
         del self.active[req.rid]
         self.completed[req.rid] = req
-        self.dispatch_stats.record(DispatchSample(
-            workload=f"request-{req.rid}", workload_class="heavy",
-            executor_class="container", executor="serving-engine",
-            node="local", wall_s=now - req.submitted_at, cold=False,
-            footprint_bytes=self.kv.bytes_in_use(),
-            replica=self.replica_id))
         if req.future is not None and not req.future.done():
             req.future.set_result(req)
 
@@ -1372,6 +1424,9 @@ class ServingEngine:
         return d.footprint_bytes() if d is not None else 0
 
     def stats(self) -> Dict[str, float]:
+        """Counters since the engine started, and percentiles: the
+        p50/p95 tick keys cover the last 512 ticks, the request keys every
+        completed request."""
         with self._lock:
             done = list(self.completed.values())
             out = {
@@ -1386,6 +1441,8 @@ class ServingEngine:
                 (self.prefix_hits + self.prefix_misses)
                 if (self.prefix_hits + self.prefix_misses) else 0.0,
                 "failed": len(self.failed),
+                "decode_tokens_committed": self.decode_tokens_committed,
+                "max_prefill_tokens_tick": self.max_prefill_tokens_tick,
                 "slot_utilization": self.kv.utilization(),
                 "paged": self.paged,
                 "kv_bytes_in_use": self.kv.bytes_in_use(),
@@ -1433,9 +1490,6 @@ class ServingEngine:
             if xs:
                 for q in (50, 95):
                     out[f"p{q}_{name}"] = percentile(xs, q)
-        if ticks:
-            out["max_prefill_tokens_tick"] = max(t[2] for t in ticks)
-            out["decode_tokens_committed"] = sum(t[4] for t in ticks)
         ttfts = [r.first_token_at - r.submitted_at for r in done
                  if r.first_token_at is not None]
         queued = [r.admitted_at - r.submitted_at for r in done

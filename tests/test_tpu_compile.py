@@ -3,12 +3,14 @@ widths (Hq=32, Hkv=4, D=64, page 16, d_model 2048).
 
 Nothing runs: the TPU compiler refuses here what the chip would refuse
 (unaligned block shapes, over-budget VMEM) — faults interpret mode cannot
-see.  Every test asserts the compiled program holds the Pallas kernel
-(``tpu_custom_call``).  The topology is described inside a fixture, so
-only the worker that runs this file loads the TPU compiler.
+see.  Every test asserts the compiled program holds the Pallas kernels
+(``tpu_custom_call``) under their own names, the names a device trace
+shows.  The topology is described inside a fixture, so only the worker
+that runs this file loads the TPU compiler.
 """
 import dataclasses
 import functools
+import re
 import types
 
 import jax
@@ -58,9 +60,22 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(fn, *args):
+_CUSTOM_CALL = re.compile(
+    r"^\s*%([\w-]+?)(?:\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+    re.M)
+
+
+def _assert_kernel(names, fn, *args):
+    """``fn`` compiles for the chip, and its Pallas kernels are the
+    ``tpu_custom_call`` instructions, each named after one of ``names``
+    (under ``jax.grad`` the name gains a ``jvp_``/``transpose_`` wrap)."""
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    calls = _CUSTOM_CALL.findall(text)
+    assert calls
+    for call in calls:
+        assert any(n in call for n in names), call
+    for n in names:
+        assert any(n in call for call in calls), n
 
 
 def _pool_args(s, kv_dtype):
@@ -82,10 +97,12 @@ def test_paged_attention_compiles(one_chip, kv_dtype, k1):
     else:
         q = _sds(one_chip, (SLOTS, HQ, D), jnp.bfloat16)
         kernel = paged_decode_attention
+    names = [kernel.__name__]
     if ks is None:
-        _assert_kernel(kernel, q, *pool, *table)
+        _assert_kernel(names, kernel, q, *pool, *table)
     else:
         _assert_kernel(
+            names,
             lambda q, k, v, t, n, a, b: kernel(q, k, v, t, n,
                                                k_scale=a, v_scale=b),
             q, *pool, *table, ks, vs)
@@ -97,6 +114,7 @@ def test_flash_forward_prefill_chunk_compiles(one_chip, batch):
     ``attention.prefill_chunk_paged`` calls it."""
     s, tq, tk = one_chip, 64, MAX_SEQ
     _assert_kernel(
+        ["flash_attention"],
         lambda q, k, v, qp, kp, n: flash_attention(
             q, k, v, q_positions=qp, kv_positions=kp, kv_valid_len=n),
         _sds(s, (batch, tq, HQ, D), jnp.bfloat16),
@@ -112,19 +130,20 @@ def test_flash_backward_compiles(one_chip):
                         argnums=(0, 1, 2))(q, k, v)
 
     x = [_sds(one_chip, (2, 256, h, D), jnp.bfloat16) for h in (HQ, HKV, HKV)]
-    _assert_kernel(grads, *x)
+    _assert_kernel(["flash_attention", "flash_attention_bwd_dq",
+                    "flash_attention_bwd_dkv"], grads, *x)
 
 
 def test_dense_decode_compiles(one_chip):
     s = one_chip
-    _assert_kernel(decode_attention, _sds(s, (SLOTS, HQ, D), jnp.bfloat16),
+    _assert_kernel(["decode_attention"], decode_attention, _sds(s, (SLOTS, HQ, D), jnp.bfloat16),
                    _sds(s, (SLOTS, MAX_SEQ, HKV, D), jnp.bfloat16),
                    _sds(s, (SLOTS, MAX_SEQ, HKV, D), jnp.bfloat16),
                    _sds(s, (SLOTS,), jnp.int32))
 
 
 def test_rmsnorm_compiles(one_chip):
-    _assert_kernel(rmsnorm, _sds(one_chip, (SLOTS, 1, 2048), jnp.bfloat16),
+    _assert_kernel(["rmsnorm"], rmsnorm, _sds(one_chip, (SLOTS, 1, 2048), jnp.bfloat16),
                    _sds(one_chip, (2048,), jnp.float32))
 
 
@@ -151,10 +170,28 @@ def test_engine_decode_step_compiles(one_chip):
                                                   jnp.bfloat16)))
     ops.set_impl("pallas")
     try:
-        _assert_kernel(step, params, pools,
+        _assert_kernel(["paged_decode_attention"], step, params, pools,
                        _sds(one_chip, (SLOTS, MP), jnp.int32),
                        _sds(one_chip, (SLOTS,), jnp.int32),
                        _sds(one_chip, (SLOTS,), jnp.int32),
                        _sds(one_chip, (SLOTS,), jnp.bool_))
     finally:
         ops.set_impl(None)
+
+
+def test_engine_programs_keep_their_names(exact_config):
+    """A device trace finds the engine's decode and prefill-chunk programs
+    by name (``jit__decode_paged_fn``, ``jit__chunk_paged_fn``)."""
+    from repro.serving.engine import ServingEngine
+
+    eng = ServingEngine(exact_config("tinyllama-1.1b"), max_slots=2,
+                        max_seq=64)
+    zero1 = jnp.zeros((1,), jnp.int32)
+    row = jnp.zeros((1, eng.kv.pages_per_slot), jnp.int32)
+    decode = eng._decode.lower(eng.params, eng.kv.pools, eng.kv.page_table,
+                               eng.last_tokens, eng.kv.cache_len,
+                               jnp.zeros((2,), bool))
+    chunk = eng._chunk.lower(eng.params, eng.kv.pools,
+                             jnp.zeros((1, 16), jnp.int32), row, zero1, zero1)
+    assert decode.as_text().startswith("module @jit__decode_paged_fn ")
+    assert chunk.as_text().startswith("module @jit__chunk_paged_fn ")
