@@ -3,17 +3,31 @@
 The KV cache lives in a pool of fixed-size pages (``[P, page, Hkv, D]``)
 instead of one dense ``[B, S, Hkv, D]`` tensor; each sequence owns a row
 of a page table mapping its logical pages to physical page ids.  The
-kernel keeps the online-softmax structure of ``decode_attention`` — the
-query tile stays VMEM-resident while the cache streams HBM→VMEM — but the
-cache blocks are *gathered through the page table*: the page table (and
-``cache_len``) ride in scalar-prefetch SMEM so the block index map can
-pick the physical page before the DMA is issued
-(``pltpu.PrefetchScalarGridSpec``).
+kernel reads the pool in that stored layout, straight from HBM: each
+page, all ``Hkv`` heads of its ``page`` tokens, is one contiguous async
+copy into a VMEM buffer.
 
-Grid = (B·Hkv, MP) with the page dimension sequential.  Logical pages at
-or beyond ``ceil(cache_len / page)`` may map to any physical page (the
-pool's page 0 is the allocator's trash page) — the validity mask zeroes
-their contribution, so stale table entries only cost the DMA.
+Grid = (B, cdiv(MP, ppb)), both axes sequential.  One grid step takes a
+block of ``ppb`` pages (about 512 tokens, fixed by the static page and
+table shapes) of one sequence and computes every query head against it
+with an online softmax carried in VMEM across the row's blocks.  Only
+live pages are copied and computed: for row ``b`` they are
+``[first, ceil(cache_len[b] / page))``, ``first`` being 0, or the page
+holding ``cache_len - window`` under a sliding window.  A block outside
+that range issues no copy and does no work; the last block copies only
+its live pages, and tokens outside the live range (unfetched buffer
+rows, or a live page's unwritten tail) are masked out of the logits and
+zeroed in V before ``p·v``.  Copies are double-buffered: while a block
+computes, the next live block (the row's next, or the next row's first)
+is already in flight into the other buffer, tracked by one DMA
+semaphore per buffer.
+
+Pools whose pages a copy cannot slice out of HBM (a last dim that is not
+a multiple of 128 lanes: head dims below 128, and the per-token scale
+planes ``[P, page, Hkv]`` of int8 pools) take a page walk instead: grid
+(B, MP), one page of all heads per step through BlockSpecs, whose index
+map pins every dead step to the nearest live page so that it issues no
+copy.  Both walks share the block computation.
 """
 from __future__ import annotations
 
@@ -27,57 +41,174 @@ from jax.experimental import pallas as pl
 from repro.kernels.flash_attention import pl_scratch
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+BLOCK_TOKENS = 512          # tokens a grid step covers (whole pages)
 
 
-def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-            sm_scale: float, softcap: float, window: int,
-            page: int, n_pages: int, hkv: int):
-    if len(rest) == 6:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, acc_ref, m_ref, l_ref = rest
-    ip = pl.program_id(1)
+def _live_pages(len_ref, row, *, page: int, window: int):
+    """Page range ``[first, end)`` that holds row ``row``'s live tokens."""
+    n = len_ref[row]
+    end = (n + page - 1) // page
+    first = jnp.maximum(n - window, 0) // page if window > 0 else 0
+    return first, end
 
-    @pl.when(ip == 0)
-    def _init():
+
+def _copy_kernel(table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                 v_buf, sem, slot_ref, acc_ref, m_ref, l_ref, *, page: int,
+                 ppb: int, batch: int, n_steps: int, window: int, **attend):
+    """Multi-page walk: the pool stays in HBM and the kernel copies the
+    live pages of each ``ppb``-page block itself, double-buffered."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, j = pl.program_id(0), pl.program_id(1)
+    live = functools.partial(_live_pages, len_ref, page=page, window=window)
+
+    def blocks(row):
+        """Block range ``[lo, hi)`` holding the row's live pages."""
+        first, end = live(row)
+        return first // ppb, (end + ppb - 1) // ppb
+
+    def copy_block(row, blk, slot, wait: bool):
+        """Start (or wait for) the copies of block ``blk`` of ``row``'s
+        live pages into buffer ``slot``."""
+        first, end = live(row)
+
+        def one_page(p, carry):
+            pid = table_ref[row, p]
+            for src, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                cp = pltpu.make_async_copy(src.at[pid],
+                                           buf.at[slot, p - blk * ppb],
+                                           sem.at[slot])
+                if wait:
+                    cp.wait()
+                else:
+                    cp.start()
+            return carry
+
+        jax.lax.fori_loop(jnp.maximum(first, blk * ppb),
+                          jnp.minimum(end, (blk + 1) * ppb), one_page, 0)
+
+    _init(j, acc_ref, m_ref, l_ref)
+
+    @pl.when((b == 0) & (j == 0))
+    def _first_step():
+        slot_ref[0] = 0
+
+    lo, hi = blocks(b)
+
+    @pl.when((j >= lo) & (j < hi))
+    def _block():
+        slot = slot_ref[0]
+        prev_lo, prev_hi = blocks(jnp.maximum(b - 1, 0))
+
+        # the previous live block prefetched this one, unless this is the
+        # row's first and the previous row had no live pages
+        @pl.when((j == lo) & ((b == 0) | (prev_hi == prev_lo)))
+        def _fetch_now():
+            copy_block(b, j, slot, wait=False)
+
+        nxt = jnp.minimum(b + 1, batch - 1)
+        next_lo, next_hi = blocks(nxt)
+
+        @pl.when(j + 1 < hi)
+        def _prefetch_in_row():
+            copy_block(b, j + 1, 1 - slot, wait=False)
+
+        @pl.when((j + 1 == hi) & (b + 1 < batch) & (next_hi > next_lo))
+        def _prefetch_next_row():
+            copy_block(nxt, next_lo, 1 - slot, wait=False)
+
+        copy_block(b, j, slot, wait=True)
+        _attend(q_ref, (k_buf.at[slot], v_buf.at[slot]), acc_ref, m_ref,
+                l_ref, n=len_ref[b], base=j * ppb * page, window=window,
+                **attend)
+        slot_ref[0] = 1 - slot
+
+    _finish(j, n_steps - 1, o_ref, acc_ref, l_ref)
+
+
+def _page_kernel(table_ref, len_ref, q_ref, *rest, page: int, n_steps: int,
+                 window: int, **attend):
+    """Page-per-step walk, for pools the copies cannot slice (see
+    ``paged_decode_attention``): the BlockSpec index map pins dead steps to
+    the nearest live page, so they issue no copy, and ``pl.when`` skips
+    their compute."""
+    del table_ref
+    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    first, end = _live_pages(len_ref, b, page=page, window=window)
+    _init(j, acc_ref, m_ref, l_ref)
+
+    @pl.when((j >= first) & (j < end))
+    def _page():
+        _attend(q_ref, rest[:-4], acc_ref, m_ref, l_ref, n=len_ref[b],
+                base=j * page, window=window, **attend)
+
+    _finish(j, n_steps - 1, o_ref, acc_ref, l_ref)
+
+
+def _init(j, acc_ref, m_ref, l_ref):
+    """A row's first grid step resets the online-softmax state."""
+    @pl.when(j == 0)
+    def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * sm_scale          # [G, D]
-    k = k_ref[0, 0].astype(jnp.float32)                  # [page, D]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [G, page]
-    if ks_ref is not None:
-        # int8 pool: fold the per-token dequant scale into the logits
-        # (q·(k·s) == (q·k)·s) instead of dequantizing the tile
-        s = s * ks_ref[0, 0]                             # [1, page]
+
+def _finish(j, last, o_ref, acc_ref, l_ref):
+    """A row's last grid step writes its output (0 for no live token)."""
+    @pl.when(j == last)
+    def _():
+        l = l_ref[...]
+        o = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = jnp.where(l == 0.0, 0.0, o).astype(o_ref.dtype)
+
+
+def _attend(q_ref, pages, acc_ref, m_ref, l_ref, *, n, base, window,
+            sm_scale, softcap):
+    """Fold one block of pages into the online softmax: ``pages`` holds K
+    and V refs ``[ppb, page, Hkv, D]`` and, for int8 pools, their scale
+    refs ``[ppb, page, Hkv]``; the block's first token is ``base``."""
+    k_ref, v_ref, *scales = pages
+    ppb, page, hkv, d = k_ref.shape
+    rows = ppb * page * hkv
+    group = q_ref.shape[1] // hkv
+    k = k_ref[...].astype(jnp.float32)                   # [ppb, page, Hkv, D]
+    v = v_ref[...].astype(jnp.float32)
+    if scales:
+        k = k * scales[0][...][..., None]
+        v = v * scales[1][...][..., None]
+    k = k.reshape(rows, d)
+    v = v.reshape(rows, v.shape[-1])
+    q = q_ref[0].astype(jnp.float32) * sm_scale          # [Hq, D]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [Hq, rows]
     if softcap > 0.0:
         s = jnp.tanh(s / softcap) * softcap
 
-    valid = len_ref[pl.program_id(0) // hkv]
-    pos = ip * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = pos < valid
-    if window > 0:
-        mask &= pos >= valid - window
+    def live(pos):
+        ok = pos < n
+        return ok & (pos >= n - window) if window > 0 else ok
+
+    # row r of the flat block is token r // Hkv of KV head r % Hkv; the
+    # query heads of group h see only head h's rows
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+    mask = live(base + col // hkv) & (col % hkv == head)
     s = jnp.where(mask, s, NEG_INF)
+    # rows not copied this block (or past the row's length) may hold
+    # anything, NaN included: zero them, since 0 · NaN is NaN
+    vrow = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    v = jnp.where(live(base + vrow // hkv), v, 0.0)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    if vs_ref is not None:
-        p = p * vs_ref[0, 0]                             # p·(v·s) == (p·s)·v
-    v = v_ref[0, 0].astype(jnp.float32)                  # [page, Dv]
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(p, v)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
+        p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
-
-    @pl.when(ip == n_pages - 1)
-    def _finish():
-        l = l_ref[...]
-        o = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = jnp.where(l == 0.0, 0.0, o).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -97,56 +228,59 @@ def paged_decode_attention(
     from jax.experimental.pallas import tpu as pltpu
 
     B, Hq, D = q.shape
-    P, page, Hkv, Dv = (k_pages.shape[0], k_pages.shape[1],
-                        k_pages.shape[2], v_pages.shape[3])
+    page, Dv = k_pages.shape[1], v_pages.shape[3]
     MP = page_table.shape[1]
-    G = Hq // Hkv
     scale = sm_scale if sm_scale is not None else D ** -0.5
+    quantized = k_scale is not None
+    attend = dict(sm_scale=scale, softcap=softcap, window=window, page=page)
+    q_spec = pl.BlockSpec((1, Hq, D), lambda b, j, pt, cl: (b, 0, 0))
+    o_spec = pl.BlockSpec((1, Hq, Dv), lambda b, j, pt, cl: (b, 0, 0))
+    pools = [k_pages, v_pages]
+    if quantized:
+        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+    state = [pl_scratch((Hq, Dv)), pl_scratch((Hq, 1)), pl_scratch((Hq, 1))]
 
-    qr = q.reshape(B * Hkv, G, D)
-    # [P, Hkv, page, D]: one (page, head) tile per gathered cache block
-    kr = k_pages.transpose(0, 2, 1, 3)
-    vr = v_pages.transpose(0, 2, 1, 3)
-    grid = (B * Hkv, MP)
+    # A copy cannot slice a page out of an HBM array whose last dim is not
+    # a multiple of 128 lanes (head dims below 128, the int8 scale planes):
+    # such pools take the page walk, fed one page a step by BlockSpecs.
+    if D % 128 == 0 and Dv % 128 == 0 and not quantized:
+        ppb = max(1, min(MP, BLOCK_TOKENS // page))
+        grid = (B, pl.cdiv(MP, ppb))
+        kernel = functools.partial(_copy_kernel, ppb=ppb, batch=B,
+                                   n_steps=grid[1], **attend)
+        # HBM, not ANY: under ANY the compiler may stage a small pool in
+        # VMEM, a copy of the whole pool on every call
+        in_specs = [q_spec] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
+        scratch = [pltpu.VMEM((2, ppb) + x.shape[1:], x.dtype) for x in pools]
+        scratch += [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
+    else:
+        def page_at(b, j, pt, cl):
+            first, end = _live_pages(cl, b, page=page, window=window)
+            return pt[b, jnp.minimum(jnp.maximum(j, first),
+                                     jnp.maximum(end - 1, 0))]
 
-    kernel = functools.partial(
-        _kernel, sm_scale=scale, softcap=softcap, window=window,
-        page=page, n_pages=MP, hkv=Hkv)
-
-    in_specs = [
-        pl.BlockSpec((1, G, D), lambda bh, ip, pt, cl: (bh, 0, 0)),
-        pl.BlockSpec((1, 1, page, D),
-                     lambda bh, ip, pt, cl: (pt[bh // Hkv, ip],
-                                             bh % Hkv, 0, 0)),
-        pl.BlockSpec((1, 1, page, Dv),
-                     lambda bh, ip, pt, cl: (pt[bh // Hkv, ip],
-                                             bh % Hkv, 0, 0)),
-    ]
-    inputs = [qr, kr, vr]
-    if k_scale is not None:
-        sc_spec = pl.BlockSpec((1, 1, 1, page),
-                               lambda bh, ip, pt, cl: (pt[bh // Hkv, ip],
-                                                       bh % Hkv, 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        # [P, Hkv, 1, page]: one lane-major scale row per (page, head)
-        # tile — the unit axis keeps the block's last two dims whole
-        inputs += [k_scale.transpose(0, 2, 1)[:, :, None].astype(jnp.float32),
-                   v_scale.transpose(0, 2, 1)[:, :, None].astype(jnp.float32)]
+        grid = (B, MP)
+        kernel = functools.partial(_page_kernel, n_steps=MP, **attend)
+        in_specs = [q_spec] + [
+            pl.BlockSpec((1,) + x.shape[1:],
+                         lambda b, j, pt, cl, r=x.ndim - 1:
+                         (page_at(b, j, pt, cl),) + (0,) * r)
+            for x in pools]
+        scratch = []
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # page_table, cache_len
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, G, Dv), lambda bh, ip, pt, cl: (bh, 0, 0)),
-        scratch_shapes=[
-            pl_scratch((G, Dv)), pl_scratch((G, 1)), pl_scratch((G, 1)),
-        ],
+        out_specs=o_spec,
+        scratch_shapes=scratch + state,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
-    )(page_table.astype(jnp.int32), cache_len.astype(jnp.int32), *inputs)
-    return out.reshape(B, Hq, Dv)
+    )(page_table.astype(jnp.int32), cache_len.astype(jnp.int32), q, *pools)

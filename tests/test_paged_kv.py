@@ -11,6 +11,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.models.attention import _quantize
 from repro.models.model import build_model
 from repro.serving.engine import ServingEngine
 from repro.serving.kv_cache import PagedKVCache
@@ -31,30 +32,87 @@ def _tol(dtype):
 # ---------------------------------------------------------------------------
 
 PAGED_CASES = [
-    # B, Hq, Hkv, D, page, MP, num_pages, window, softcap
-    (2, 4, 2, 32, 16, 4, 11, 0, 0.0),          # GQA
-    (3, 8, 1, 64, 16, 8, 30, 0, 0.0),          # MQA, more pages
-    (1, 4, 4, 32, 32, 4, 9, 48, 0.0),          # MHA + sliding window
-    (2, 8, 2, 32, 16, 6, 15, 0, 20.0),         # logit softcap
-    (2, 16, 2, 128, 8, 4, 12, 0, 0.0),         # MXU-wide head, small page
+    # B, Hq, Hkv, D, page, MP, num_pages, window, softcap, cache_len (None:
+    # random), all-zero table rows
+    (2, 4, 2, 32, 16, 4, 11, 0, 0.0, None, ()),          # GQA
+    (3, 8, 1, 64, 16, 8, 30, 0, 0.0, None, ()),          # MQA, more pages
+    (1, 4, 4, 32, 32, 4, 9, 48, 0.0, None, ()),          # MHA + sliding window
+    (2, 8, 2, 32, 16, 6, 15, 0, 20.0, None, ()),         # logit softcap
+    (2, 16, 2, 128, 8, 4, 12, 0, 0.0, None, ()),         # MXU-wide head, small page
+    # multi-page blocks (D a multiple of 128): 32 pages of 16 per block
+    (2, 8, 2, 128, 16, 40, 90, 0, 0.0, (1, 640), ()),    # MP % ppb, len 1, MP·page
+    (3, 8, 2, 128, 16, 40, 90, 0, 0.0, (300, 1, 517), (1,)),   # zero table row
+    (3, 8, 2, 128, 16, 40, 90, 0, 0.0, (33, 200, 1), (1, 2)),  # zero rows, long
+    (2, 8, 2, 128, 16, 80, 170, 300, 0.0, (1000, 250), ()),    # window mid-block
+    (2, 8, 2, 128, 16, 528, 600, 0, 0.0, None, ()),      # the rag cell's table
+    (2, 8, 2, 64, 16, 40, 90, 300, 0.0, (1, 640), (0,)),  # page walk: edges
 ]
 
 
-@pytest.mark.parametrize("case", PAGED_CASES)
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_kernel_vs_ref(case, dtype):
-    B, Hq, Hkv, D, page, MP, P, window, softcap = case
+def _paged_inputs(case, dtype):
+    B, Hq, Hkv, D, page, MP, P, window, softcap, lens, zero_rows = case
     ks = jax.random.split(jax.random.key(B * 31 + MP), 5)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
     kp = jax.random.normal(ks[1], (P, page, Hkv, D), dtype)
     vp = jax.random.normal(ks[2], (P, page, Hkv, D), dtype)
     table = jax.random.randint(ks[3], (B, MP), 0, P)
-    clen = jax.random.randint(ks[4], (B,), 1, MP * page + 1)
+    for row in zero_rows:
+        table = table.at[row].set(0)
+    clen = (jax.random.randint(ks[4], (B,), 1, MP * page + 1) if lens is None
+            else jnp.asarray(lens, jnp.int32))
+    return q, kp, vp, table, clen, window, softcap
+
+
+@pytest.mark.parametrize("case", PAGED_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_kernel_vs_ref(case, dtype):
+    q, kp, vp, table, clen, window, softcap = _paged_inputs(case, dtype)
     want = ref.paged_decode_attention(q, kp, vp, table, clen,
                                       window=window, softcap=softcap)
     got = paged_decode_attention(q, kp, vp, table, clen, window=window,
                                  softcap=softcap, interpret=True)
     assert _rel_err(want, got) < _tol(dtype)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8", "bf16_d64"])
+def test_paged_decode_kernel_ignores_dead_pages(kv):
+    """Pages the table references only past a row's live length hold NaN
+    (int8: NaN and inf scale planes too); the kernel must neither read
+    them into the result nor let ``0 · NaN`` through ``p·v``, and must
+    still equal the oracle on the live context."""
+    D = 64 if kv == "bf16_d64" else 128
+    B, Hq, Hkv, page, MP = 3, 8, 2, 16, 40
+    P = B * MP + 1
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (B, Hq, D), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (P, page, Hkv, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, page, Hkv, D), jnp.float32)
+    # distinct pages per row; lengths end mid-page, mid-block and at 1
+    table = np.arange(1, P).reshape(B, MP).astype(np.int32)
+    clen = np.array([37, 555, 1], np.int32)
+    live = {int(table[b, m]) for b in range(B)
+            for m in range(-(-int(clen[b]) // page))}
+    dead = np.array(sorted(set(table.ravel().tolist()) - live))
+    scales = {}
+    if kv == "int8":
+        kp, k_s = _quantize(kp)
+        vp, v_s = _quantize(vp)
+        scales = dict(k_scale=k_s, v_scale=v_s)
+        dirty = dict(k_scale=k_s.at[dead].set(jnp.nan),
+                     v_scale=v_s.at[dead].set(jnp.inf))
+    else:
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+        dirty = {}
+    want = ref.paged_decode_attention(q, kp, vp, jnp.asarray(table),
+                                      jnp.asarray(clen), **scales)
+    if kv != "int8":
+        kp = kp.at[dead].set(jnp.nan)
+        vp = vp.at[dead].set(jnp.nan)
+    got = paged_decode_attention(q, kp, vp, jnp.asarray(table),
+                                 jnp.asarray(clen), interpret=True,
+                                 **(dirty or scales))
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    assert _rel_err(want, got) < _tol(jnp.bfloat16)
 
 
 def test_paged_ref_equals_dense_layout():

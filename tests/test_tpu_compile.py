@@ -15,6 +15,7 @@ import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -61,7 +62,8 @@ def _sds(sharding, shape, dtype):
 
 
 _CUSTOM_CALL = re.compile(
-    r"^\s*%([\w-]+?)(?:\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+    r"^\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)? = "
+    r".*custom_call_target=\"tpu_custom_call\"",
     re.M)
 
 
@@ -106,6 +108,32 @@ def test_paged_attention_compiles(one_chip, kv_dtype, k1):
             lambda q, k, v, t, n, a, b: kernel(q, k, v, t, n,
                                                k_scale=a, v_scale=b),
             q, *pool, *table, ks, vs)
+
+
+# the benchmark cells' decode shapes: B, Hq, Hkv, D, page, MP
+CELL_DECODE_SHAPES = {"chat": (32, 64, 8, 128, 16, 128),   # chameleon-34b.l6
+                      "rag": (8, 64, 8, 128, 16, 528)}     # command-r-35b.l5
+_MOVE = re.compile(r"^\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]*)\]\S* .*?"
+                   r"\b(copy|copy-start|transpose|fusion)\(", re.M)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_DECODE_SHAPES))
+def test_paged_decode_compiles_at_cell_shapes(one_chip, cell):
+    """At the cells' widths and table sizes the decode kernel is one
+    ``paged_decode_attention`` call that reads the pool as stored: no copy,
+    transpose or fusion around it writes a pool-sized array."""
+    B, Hq, Hkv, D, page, MP = CELL_DECODE_SHAPES[cell]
+    pool = (B * MP + 1, page, Hkv, D)
+    text = jax.jit(paged_decode_attention).lower(
+        _sds(one_chip, (B, Hq, D), jnp.bfloat16),
+        _sds(one_chip, pool, jnp.bfloat16), _sds(one_chip, pool, jnp.bfloat16),
+        _sds(one_chip, (B, MP), jnp.int32),
+        _sds(one_chip, (B,), jnp.int32)).compile().as_text()
+    assert _CUSTOM_CALL.findall(text) == ["paged_decode_attention"]
+    pool_elems = int(np.prod(pool))
+    moves = [(dims, op) for dims, op in _MOVE.findall(text)
+             if np.prod([int(d) for d in dims.split(",") if d]) >= pool_elems]
+    assert not moves, moves
 
 
 @pytest.mark.parametrize("batch", [1, SLOTS])
