@@ -2,9 +2,10 @@
 
 After the window, a sample of the requests the engine finished, drawn
 from the seed with the longest among them, is run once through the
-configuration's plain float32 reference, teacher-forced with the served
-tokens.  The number compared is the widest gap by which a served token's
-reference logit lies below the reference's best at that position.
+configuration's plain float32 reference (``spec.load_reference``),
+teacher-forced with the served tokens.  The number compared is the widest
+gap by which a served token's reference logit lies below the reference's
+best at that position.
 
 The control puts the reference, computed one precision step below the
 configuration (float8 matmuls), in the program's place: at each position
@@ -12,23 +13,12 @@ of the same prompts and served tokens, the token it puts first.
 """
 from __future__ import annotations
 
-import importlib.util
 from types import SimpleNamespace
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
 from benchlib import traffic as tr
-from benchlib.spec import BENCH_DIR
-
-
-def load_reference(conf: Dict[str, Any]):
-    path = BENCH_DIR / "configs" / f"{conf['reference']}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_ref_{conf['reference']}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def sample(finished: List[Any], seed: int, max_requests: int,

@@ -9,17 +9,17 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, List, Optional
 
-from benchlib import work
 from benchlib.peaks import Peaks
 from benchlib.serve import Served
 from benchlib.tracefile import Trace
 from benchlib.traffic import percentile
+from benchlib.work import Progress, Work, least_seconds
 
 
 @dataclasses.dataclass
 class RunView:
     served: Served
-    shapes: work.Shapes
+    work: Work             # the configuration's counts (its architecture)
     peaks: Peaks
     setup_s: float
     trace: Optional[Trace]
@@ -29,7 +29,7 @@ def window_s(run: RunView, a: str = "window0", b: str = "window1") -> float:
     return run.served.times[b] - run.served.times[a]
 
 
-def progress(run: RunView, a: str, b: str) -> List[work.Progress]:
+def progress(run: RunView, a: str, b: str) -> List[Progress]:
     """Each request's (prompt length, prefilled and generated at edge a,
     at edge b).  Tokens a prefix match skipped were never prefilled."""
     out = []
@@ -70,8 +70,7 @@ def ticks(run: RunView, a: str = "window0", b: str = "window1") -> int:
 
 
 def mfu_percent(run: RunView) -> Optional[float]:
-    flops = work.served_flops(run.shapes, progress(run, "window0",
-                                                   "window1"))
+    flops = run.work.served_flops(progress(run, "window0", "window1"))
     if flops == 0:
         return None
     return 100.0 * flops / window_s(run) / run.peaks.bf16_flops
@@ -86,8 +85,8 @@ def roofline_percent(run: RunView, flops: int, nbytes: int,
     spent = run.trace.kernel_s(module_prefix)
     if spent <= 0.0 or (flops == 0 and nbytes == 0):
         return None
-    least = work.least_seconds(flops, nbytes, run.peaks.bf16_flops,
-                               run.peaks.hbm_bytes_per_s)
+    least = least_seconds(flops, nbytes, run.peaks.bf16_flops,
+                          run.peaks.hbm_bytes_per_s)
     return 100.0 * least / spent
 
 
@@ -97,5 +96,5 @@ def idle_percent(run: RunView) -> Optional[float]:
     return 100.0 * (1.0 - run.trace.busy_s() / run.trace.window_s)
 
 
-def trace_progress(run: RunView) -> List[work.Progress]:
+def trace_progress(run: RunView) -> List[Progress]:
     return progress(run, "trace0", "trace1")

@@ -9,12 +9,14 @@ the chip at these sizes).
 The tree has the program's parameter layout (read with ``jax.eval_shape``
 of its ``init``, which allocates nothing); every leaf is filled by the
 benchmark from its name, so the plain reference reads the same weights
-without taking anything the program made.
+without taking anything the program made.  The rules every architecture
+shares are here; an architecture module gives the rules for the leaves it
+adds (``weight_rule``), and a leaf neither knows is an error.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Callable
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,8 +61,14 @@ def _normal(shape, w1, w2):
     return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(2.0 * jnp.pi * u2)
 
 
-def _rule(path: str, shape, d_model: int):
-    """(kind, std) for a leaf, by its name in the program's layout."""
+Rule = Tuple[str, float]
+# an architecture's rules: (kind, std) for the leaves it adds, else None
+ArchRule = Callable[[str, Tuple[int, ...], int], Optional[Rule]]
+
+
+def _shared_rule(path: str, shape, d_model: int) -> Optional[Rule]:
+    """(kind, std) for a leaf every architecture may have, by its name in
+    the program's layout."""
     name = path.rsplit("/", 1)[-1]
     if name == "embedding":
         return "normal", 0.02
@@ -74,17 +82,29 @@ def _rule(path: str, shape, d_model: int):
         return "normal", 1.0 / math.sqrt(shape[-3] * shape[-2])
     if name == "w_down":         # [L, d_ff, d]
         return "normal", 1.0 / math.sqrt(shape[-2])
-    raise ValueError(f"no weight rule for leaf {path!r}")
+    return None
+
+
+def rule(path: str, shape, d_model: int,
+         arch_rule: Optional[ArchRule] = None) -> Rule:
+    """(kind, std) for a leaf: the shared rules, then the architecture's."""
+    found = _shared_rule(path, shape, d_model)
+    if found is None and arch_rule is not None:
+        found = arch_rule(path, tuple(shape), d_model)
+    if found is None:
+        raise ValueError(f"no weight rule for leaf {path!r}")
+    return found
 
 
 def make_weights(init_fn: Callable[[Any], Any], d_model: int, seed: int,
-                 dtype=jnp.bfloat16):
-    """Weights in the layout of ``init_fn``'s output, from ``seed``."""
+                 dtype=jnp.bfloat16, arch_rule: Optional[ArchRule] = None):
+    """Weights in the layout of ``init_fn``'s output, from ``seed``, with
+    ``arch_rule`` for the leaves the shared rules do not know."""
     abstract = jax.eval_shape(init_fn, jax.random.key(0))
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
     paths = ["/".join(str(getattr(k, "key", k)) for k in p) for p, _ in flat]
     shapes = [tuple(a.shape) for _, a in flat]
-    rules = [_rule(p, s, d_model) for p, s in zip(paths, shapes)]
+    rules = [rule(p, s, d_model, arch_rule) for p, s in zip(paths, shapes)]
 
     words = jnp.asarray([_words(seed, i) for i in range(len(shapes))],
                         jnp.uint32)

@@ -1,135 +1,47 @@
-"""Operations and bytes of the work served, counted from a configuration's
-shapes and from what the window served.  The benchmark divides these by
-times it measured, whatever code did the work, so a later change to the
-program cannot change how its work is counted.
+"""Operations and bytes of the work served: what every architecture's
+counts share.  A configuration's architecture module
+(``bench/configs/<architecture>.py``) counts its own model's work from the
+configuration's shapes and from what the window served, as a ``Work``.
+The benchmark divides these by times it measured, whatever code did the
+work, so a later change to the program cannot change how its work is
+counted.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Any, Dict, Iterable, Tuple
-
-
-@dataclasses.dataclass(frozen=True)
-class Shapes:
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    gated: bool            # SwiGLU: three FFN matrices, else two
-    tied: bool             # LM head is the embedding's transpose
-    qk_norm: bool
-    layernorm: bool        # LayerNorm (scale + bias) vs RMSNorm (scale)
-    parallel_block: bool   # one norm per block
-    weight_bytes: int = 2  # bf16
-    kv_bytes: int = 2      # bf16 KV pages
-    act_bytes: int = 2     # bf16 activations (q, attention output)
-
-
-def shapes_of(conf: Dict[str, Any]) -> Shapes:
-    """Shapes of a configuration file (``bench/configs/*.json``)."""
-    d, h = conf["hidden_size"], conf["num_attention_heads"]
-    return Shapes(
-        layers=conf["num_hidden_layers"], d_model=d, heads=h,
-        kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or d // h,
-        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
-        gated=conf["hidden_act"] == "silu",
-        tied=bool(conf["tie_word_embeddings"]),
-        qk_norm=bool(conf.get("qk_layernorm") or conf.get("use_qk_norm")),
-        layernorm=conf["norm"] == "layernorm",
-        parallel_block=bool(conf["parallel_block"]))
-
-
-def layer_matmul_params(s: Shapes) -> int:
-    """Parameters of one layer that a token multiplies: q, k, v, o and
-    the FFN."""
-    attn = 2 * s.d_model * s.heads * s.head_dim \
-        + 2 * s.d_model * s.kv_heads * s.head_dim
-    return attn + (3 if s.gated else 2) * s.d_model * s.d_ff
-
-
-def num_params(s: Shapes) -> int:
-    """Every parameter: embedding, untied head, layers, norms."""
-    norm = (2 if s.layernorm else 1) * s.d_model
-    per_layer = layer_matmul_params(s) \
-        + (1 if s.parallel_block else 2) * norm \
-        + (2 * s.head_dim if s.qk_norm else 0)
-    embed = s.vocab * s.d_model * (1 if s.tied else 2)
-    return embed + s.layers * per_layer + norm
-
-
-def _ctx_sum(a: int, b: int, offset: int = 0) -> int:
-    """Sum of (offset + i + 1) for i in [a, b)."""
-    n = b - a
-    if n <= 0:
-        return 0
-    return n * (offset + 1) + (b * (b - 1) - a * (a - 1)) // 2
-
+from typing import Iterable, Protocol, Tuple
 
 # One request's progress over a window: prompt length, prompt tokens
 # prefilled at the window's two edges, tokens generated at its two edges.
 Progress = Tuple[int, int, int, int, int]
 
 
-def served_flops(s: Shapes, progress: Iterable[Progress]) -> int:
-    """Model FLOPs of the work a window served.
+class Work(Protocol):
+    """What an architecture module's ``work(conf)`` returns.  A module
+    may carry more, for a kernel of its own; the readers of that kernel's
+    metrics call it."""
 
-    Each prompt token prefilled goes through every layer's matmuls and
-    attends causally over its own prefix.  The first generated token costs
-    the LM head only (its layers ran with the prompt); each later one goes
-    through every layer at its context and the head.
-    """
-    mm = 2 * s.layers * layer_matmul_params(s)
-    att = 4 * s.layers * s.heads * s.head_dim      # per (query, key) pair
-    head = 2 * s.d_model * s.vocab
-    total = 0
-    for plen, p0, p1, g0, g1 in progress:
-        total += mm * max(p1 - p0, 0) + att * _ctx_sum(p0, p1)
-        a = max(g0, 1)
-        n = max(g1 - a, 0)
-        # token j >= 1 attends over plen + j keys
-        total += mm * n + att * _ctx_sum(a, g1, offset=plen - 1)
-        total += head * max(g1 - g0, 0)
-    return total
+    def num_params(self) -> int:
+        """Every parameter of the model."""
 
+    def served_flops(self, progress: Iterable[Progress]) -> int:
+        """Model FLOPs of the work a window served."""
 
-def decode_attention_work(s: Shapes, progress: Iterable[Progress]
-                          ) -> Tuple[int, int]:
-    """(FLOPs, bytes) of the paged decode attention the window served:
-    each decode token's query against its live context's K and V, over
-    every layer.  Pages past the live context are not work."""
-    flops = nbytes = 0
-    per_key_flops = 4 * s.heads * s.head_dim
-    per_key_bytes = 2 * s.kv_heads * s.head_dim * s.kv_bytes
-    per_query_bytes = 2 * s.heads * s.head_dim * s.act_bytes
-    for plen, _p0, _p1, g0, g1 in progress:
-        a = max(g0, 1)
-        n = max(g1 - a, 0)
-        keys = _ctx_sum(a, g1, offset=plen - 1)
-        flops += per_key_flops * keys
-        nbytes += per_key_bytes * keys + per_query_bytes * n
-    return flops * s.layers, nbytes * s.layers
+    def decode_attention_work(self, progress: Iterable[Progress]
+                              ) -> Tuple[int, int]:
+        """(FLOPs, bytes) of the decode attention a window served."""
+
+    def prefill_attention_work(self, progress: Iterable[Progress],
+                               chunk: int) -> Tuple[int, int]:
+        """(FLOPs, bytes) of the chunked-prefill attention a window
+        served, ``chunk`` prompt tokens a chunk."""
 
 
-def prefill_attention_work(s: Shapes, progress: Iterable[Progress],
-                           chunk: int) -> Tuple[int, int]:
-    """(FLOPs, bytes) of the chunked-prefill attention the window served:
-    each chunk's queries attend causally over the prompt prefix up to the
-    chunk's end, whose K and V are read once per chunk."""
-    flops = nbytes = 0
-    per_key_bytes = 2 * s.kv_heads * s.head_dim * s.kv_bytes
-    per_query_bytes = 2 * s.heads * s.head_dim * s.act_bytes
-    for _plen, p0, p1, _g0, _g1 in progress:
-        start = p0
-        while start < p1:
-            end = min(start + chunk, p1)
-            flops += 4 * s.heads * s.head_dim * _ctx_sum(start, end)
-            nbytes += per_key_bytes * end + per_query_bytes * (end - start)
-            start = end
-    return flops * s.layers, nbytes * s.layers
+def ctx_sum(a: int, b: int, offset: int = 0) -> int:
+    """Sum of (offset + i + 1) for i in [a, b)."""
+    n = b - a
+    if n <= 0:
+        return 0
+    return n * (offset + 1) + (b * (b - 1) - a * (a - 1)) // 2
 
 
 def least_seconds(flops: int, nbytes: int, peak_flops: float,

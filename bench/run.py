@@ -3,11 +3,12 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 In order: read the cell's configuration and traffic files by name
-(``BENCHMARK.json``), build the configuration's ``EdgeSystem`` with
-weights made on the device from the seed, warm its programs (from the
-persistent compile cache where it holds them), offer the traffic for the
-window, check what was served against the plain reference, and print one
-JSON line.  With ``--trace 1`` a sub-window is traced and the line carries
+(``BENCHMARK.json``), take the model from the architecture module the
+configuration names, build its ``EdgeSystem`` with weights made on the
+device from the seed, warm its programs (from the persistent compile cache
+where it holds them), offer the traffic for the window, check what was
+served against the configuration's plain reference, and print one JSON
+line.  With ``--trace 1`` a sub-window is traced and the line carries
 the cell's per-layer metrics; with ``--trace 0`` its end-to-end metrics.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits
@@ -36,7 +37,7 @@ for p in (str(ROOT / "src"), str(BENCH_DIR)):
         sys.path.insert(0, p)
 
 from benchlib import check as chk  # noqa: E402
-from benchlib import serve, spec, work  # noqa: E402
+from benchlib import serve, spec  # noqa: E402
 from benchlib.peaks import Peaks, peaks_for  # noqa: E402
 from benchlib.readers import RunView  # noqa: E402
 
@@ -63,27 +64,6 @@ def enable_compile_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
-
-
-def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-
-    d, h = conf["hidden_size"], conf["num_attention_heads"]
-    if conf["hidden_act"] != "silu":
-        raise ValueError(f"unsupported activation {conf['hidden_act']!r}")
-    return ModelConfig(
-        name=conf["name"], family="dense", num_layers=conf["num_hidden_layers"],
-        d_model=d, num_heads=h, num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf.get("head_dim") or d // h,
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        activation="swiglu", attn_type="full",
-        qk_norm=bool(conf.get("qk_layernorm") or conf.get("use_qk_norm")),
-        norm=conf["norm"], norm_eps=float(conf["norm_eps"]),
-        parallel_block=bool(conf["parallel_block"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        rope_theta=float(conf["rope_theta"]),
-        param_dtype=conf["torch_dtype"], compute_dtype=conf["torch_dtype"])
 
 
 def _attempted_failed(served: serve.Served, loop: str) -> Tuple[int, int]:
@@ -126,15 +106,15 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         print(f"compile cache: {enable_compile_cache()}", file=sys.stderr)
     counter = serve.CompileCounter()
     conf, traffic = cell.config, cell.traffic
-    cfg = model_config(conf)
-    shapes = work.shapes_of(conf)
+    arch = spec.load_architecture(conf, cell.config_dir)
+    cfg = arch.model_config(conf)
 
     from repro.models.model import build_model
     from benchlib.weights import make_weights
 
     _note(t_start, f"{cell.name} on {dev.device_kind} x{len(devices)}")
     weights = make_weights(build_model(cfg).init, cfg.d_model, seed,
-                           dtype=cfg.pdtype)
+                           dtype=cfg.pdtype, arch_rule=arch.weight_rule)
     jax.block_until_ready(weights)
     _note(t_start, "weights made")
     system, engine = serve.build_system(cfg, conf, weights,
@@ -160,7 +140,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         gc.collect()
 
         checks: Dict[str, Dict[str, float]] = {}
-        ref = chk.load_reference(conf)
+        ref = spec.load_reference(conf, cell.config_dir)
         sample = chk.sample(finished, seed, int(traffic["check"]["requests"]),
                             int(traffic["check"]["tokens"]))
         pad = int(conf["serving"]["max_seq"])
@@ -188,7 +168,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
-    view = RunView(served, shapes, peaks, setup_s, tr)
+    view = RunView(served, arch.work(conf), peaks, setup_s, tr)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = m.read(view)

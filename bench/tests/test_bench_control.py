@@ -11,7 +11,7 @@ import pytest
 
 import bench_tiny
 from benchlib import check as chk
-from benchlib import serve
+from benchlib import serve, spec
 from benchlib.weights import make_weights
 
 CELLS = ["chameleon-34b.l6.chat", "command-r-35b.l5.rag"]
@@ -22,15 +22,15 @@ def test_control_reads_far_above_the_program(name):
     from repro.core.resources import NodeCapacity
     from repro.models.model import build_model
 
-    run = bench_tiny.load_run()
     cell = bench_tiny.tiny_cell(name, d_model=256, vocab=1024)
     conf, traffic = cell.config, cell.traffic
-    cfg = run.model_config(conf)
-    ref = chk.load_reference(conf)
+    arch = spec.load_architecture(conf)
+    cfg = arch.model_config(conf)
+    ref = spec.load_reference(conf)
     prog, ctl = [], []
     for seed in (1, 2, 3):
         w = make_weights(build_model(cfg).init, cfg.d_model, seed,
-                         dtype=cfg.pdtype)
+                         dtype=cfg.pdtype, arch_rule=arch.weight_rule)
         system, engine = serve.build_system(
             cfg, conf, w,
             capacity=NodeCapacity(hbm_bytes=16 << 30, chips=1,

@@ -32,3 +32,18 @@ def test_broken_decode_is_not_correct(kind, monkeypatch):
     assert not res["correct"], lines
     gap = res["checks"]["logit_gap"]
     assert gap["value"] > gap["limit"]
+
+
+@pytest.mark.parametrize("kind", ["token", "state_unchanged"])
+def test_broken_decode_of_the_routed_expert_fixture_is_not_correct(
+        kind, monkeypatch):
+    from repro.serving.engine import ServingEngine
+
+    monkeypatch.setattr(ServingEngine, "_decode_paged_fn",
+                        _faulty_decode(kind))
+    cell = bench_tiny.fixture_cell("bench/tests/data/tiny-moe.json",
+                                   "chameleon-34b.l6.chat")
+    res, lines = bench_tiny.run_tiny(cell, seed=2**31 + 9)
+    assert not res["correct"], lines
+    gap = res["checks"]["logit_gap"]
+    assert gap["value"] > gap["limit"]

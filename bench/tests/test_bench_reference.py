@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 import bench_tiny
-from benchlib import check as chk
+from benchlib import spec
 from benchlib.weights import make_weights
 
 
@@ -19,11 +19,12 @@ def test_reference_matches_the_program_forward(name):
     from repro.models.model import build_model
 
     conf = bench_tiny.tiny_cell(name).config
-    run = bench_tiny.load_run()
-    cfg = dataclasses.replace(run.model_config(conf), param_dtype="float32",
+    arch = spec.load_architecture(conf)
+    cfg = dataclasses.replace(arch.model_config(conf), param_dtype="float32",
                               compute_dtype="float32")
     model = build_model(cfg)
-    w = make_weights(model.init, cfg.d_model, 11, dtype=jnp.float32)
+    w = make_weights(model.init, cfg.d_model, 11, dtype=jnp.float32,
+                     arch_rule=arch.weight_rule)
     toks = np.random.default_rng(0).integers(0, cfg.vocab_size, 90,
                                              dtype=np.int32)
     ops.set_impl("ref")
@@ -35,7 +36,7 @@ def test_reference_matches_the_program_forward(name):
     lg = np.asarray(logits[0], np.float64)
     rows = np.arange(0, 90, 3)
     chosen = np.argsort(lg[rows], -1)[:, -2]          # the runner-up
-    ref = chk.load_reference(conf)
+    ref = spec.load_reference(conf)
     mx, am, pk = ref.logits_summary(w, conf, toks, rows, chosen, pad_to=128)
     np.testing.assert_allclose(mx, lg[rows].max(-1), atol=2e-5)
     np.testing.assert_array_equal(am, lg[rows].argmax(-1))
