@@ -1,9 +1,10 @@
 """Unified model configuration covering every assigned architecture family.
 
-One dataclass describes dense / MoE / SSM / hybrid / encoder-only models; the
-factory in ``models/model.py`` reads the fields that apply to the family and
-ignores the rest.  Every field corresponds to a published hyper-parameter of
-one of the assigned architectures (see ``repro/configs/``).
+One dataclass describes dense / MoE / SSM / hybrid / pattern / encoder-only
+models; the factory in ``models/model.py`` reads the fields that apply to
+the family and ignores the rest.  Every field corresponds to a published
+hyper-parameter of one of the assigned architectures (see
+``repro/configs/``).
 """
 from __future__ import annotations
 
@@ -60,7 +61,7 @@ class ModelConfig:
     """Architecture description.  ``family`` picks the block layout."""
 
     name: str = "model"
-    # dense | moe | ssm | hybrid | encoder
+    # dense | moe | ssm | hybrid | pattern | encoder
     family: str = "dense"
     # none | vq_tokens (chameleon) | audio_frames (hubert) — modality frontend
     # stubs: input_specs() provides precomputed embeddings / token ids.
@@ -102,6 +103,10 @@ class ModelConfig:
     # hybrid (zamba2): one *shared* attention+MLP block applied every
     # `hybrid_attn_every` SSM layers (weights shared across applications).
     hybrid_attn_every: int = 6
+    # pattern (nemotron-h): one character per layer, each layer one
+    # pre-norm mixer with its own weights added to the residual —
+    # "M" mamba2, "-" MLP, "*" attention
+    layer_pattern: str = ""
 
     # --- encoder-only (hubert) ---
     encoder_only: bool = False
@@ -137,6 +142,20 @@ class ModelConfig:
     @property
     def cdtype(self):
         return jnp.dtype(self.compute_dtype)
+
+    @property
+    def pattern_runs(self) -> Tuple[Tuple[str, int], ...]:
+        """``layer_pattern`` as runs of one kind: ``(kind, length)``."""
+        runs = []
+        for ch in self.layer_pattern:
+            if ch not in "M-*":
+                raise ValueError(f"unknown layer kind {ch!r} in "
+                                 f"{self.layer_pattern!r}")
+            if runs and runs[-1][0] == ch:
+                runs[-1][1] += 1
+            else:
+                runs.append([ch, 1])
+        return tuple((k, n) for k, n in runs)
 
     @property
     def pdtype(self):
@@ -216,6 +235,11 @@ class ModelConfig:
             n += L * (ssm_params() + norm_p) + norm_p
             # one shared attention+MLP block
             n += attn_params() + mlp_params(self.d_ff) + 2 * norm_p
+        elif self.family == "pattern":
+            per_kind = {"M": ssm_params(), "-": mlp_params(self.d_ff),
+                        "*": attn_params()}
+            n += sum(per_kind[k] + norm_p for k in self.layer_pattern)
+            n += norm_p
         else:
             raise ValueError(self.family)
         return n

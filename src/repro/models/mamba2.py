@@ -87,16 +87,38 @@ def _split_xbc(xBC, cfg: ModelConfig):
     return x_in, B_, C_
 
 
+def gated_out_norm(y, z, scale, cfg: ModelConfig):
+    """The gated RMSNorm before ``out_proj``: ``y·silu(z)`` normalised over
+    each of ``n_groups`` groups of ``d_inner / n_groups`` channels (Mamba-2's
+    and Nemotron-H's ``RMSNormGated``), then scaled.  One group is the
+    whole of ``d_inner``."""
+    h = y * jax.nn.silu(z)
+    g = cfg.ssm.n_groups
+    if g == 1:
+        return rms_norm_simple(h, scale, cfg.norm_eps)
+    shape = h.shape
+    hg = h.reshape(*shape[:-1], g, shape[-1] // g)
+    hg = rms_norm_simple(hg, jnp.ones((shape[-1] // g,), h.dtype),
+                         cfg.norm_eps)
+    return hg.reshape(shape) * scale.astype(h.dtype)
+
+
 def apply_mamba2(
     p, x: jax.Array, cfg: ModelConfig,
     state: Optional[dict] = None,
+    valid_len: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[dict]]:
     """Full-sequence SSD pass.  If ``state`` is given, the final recurrent
     state is returned (prefill → decode handoff), AND the carried conv tail
     is prepended to the conv input — so a prefill can resume mid-prompt
     (chunked prefill): the first ``d_conv - 1`` tokens of a chunk see the
     previous chunk's pre-conv stream instead of zero padding.  A zero
-    conv state reproduces the stateless path exactly."""
+    conv state reproduces the stateless path exactly.
+
+    ``valid_len`` [B]: tokens of the chunk that are real; the rest is
+    bucket padding, which leaves the state as the last real token left it
+    (its ``dt`` is 0, so it neither decays nor feeds the state, and the conv
+    tail is taken from the real tokens)."""
     s, di, H, conv_dim = _dims(cfg)
     B, T, _ = x.shape
     dt_c = cfg.cdtype
@@ -114,36 +136,50 @@ def apply_mamba2(
     x_in, B_, C_ = _split_xbc(xBC, cfg)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])   # [B,T,H]
-    A = -jnp.exp(p["a_log"])
+    if valid_len is not None:
+        real = jnp.arange(T)[None, :] < valid_len[:, None]            # [B,T]
+        dt = jnp.where(real[..., None], dt, 0.0)
+    A = -jnp.exp(p["a_log"].astype(jnp.float32))
     xh = x_in.reshape(B, T, H, s.head_dim)
     Bh = B_.reshape(B, T, s.n_groups, s.d_state)
     Ch = C_.reshape(B, T, s.n_groups, s.d_state)
 
+    # the SSD block is the sequence when it is shorter than a block and
+    # tiles as one (an engine chunk): the result does not depend on it
+    chunk = T if T < s.chunk_size and T % 8 == 0 else s.chunk_size
     init_ssm = state["ssm"] if state is not None else None
     if state is not None:
-        y, final = ops.ssd_scan(xh, dt, A, Bh, Ch, chunk=s.chunk_size,
+        y, final = ops.ssd_scan(xh, dt, A, Bh, Ch, chunk=chunk,
                                 initial_state=init_ssm, return_final_state=True)
     else:
-        y = ops.ssd_scan(xh, dt, A, Bh, Ch, chunk=s.chunk_size)
+        y = ops.ssd_scan(xh, dt, A, Bh, Ch, chunk=chunk)
         final = None
 
     y = y + xh * p["d_skip"][None, None, :, None].astype(y.dtype)
     y = y.reshape(B, T, di)
-    y = rms_norm_simple(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
+    y = gated_out_norm(y, z, p["out_norm"], cfg)
     out = y @ p["out_proj"].astype(dt_c)
 
     if state is not None:
-        # conv state holds the *pre-conv* xBC stream tail
-        new_conv = jnp.concatenate(
-            [state["conv"].astype(dt_c), xBC_raw], axis=1)[:, -(s.d_conv - 1):]
+        # conv state holds the *pre-conv* xBC stream tail: the d_conv - 1
+        # entries before the first padded token
+        stream = jnp.concatenate([state["conv"].astype(dt_c), xBC_raw], axis=1)
+        if valid_len is None:
+            new_conv = stream[:, -(s.d_conv - 1):]
+        else:
+            new_conv = jax.vmap(
+                lambda r, n: jax.lax.dynamic_slice_in_dim(
+                    r, n, s.d_conv - 1, axis=0))(stream, valid_len)
         state = {"conv": new_conv, "ssm": final}
     return out, state
 
 
 def decode_step_mamba2(
     p, x: jax.Array, cfg: ModelConfig, state: dict,
+    active: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, dict]:
-    """x: [B, 1, d] → (out [B, 1, d], new state).  O(1) per token."""
+    """x: [B, 1, d] → (out [B, 1, d], new state).  O(1) per token.  Rows
+    where ``active`` [B] is False keep their state as it was."""
     s, di, H, conv_dim = _dims(cfg)
     B = x.shape[0]
     dt_c = cfg.cdtype
@@ -158,14 +194,19 @@ def decode_step_mamba2(
     x_in, B_, C_ = _split_xbc(xBC_c, cfg)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # [B,H]
-    A = -jnp.exp(p["a_log"])
+    A = -jnp.exp(p["a_log"].astype(jnp.float32))
     xh = x_in.reshape(B, H, s.head_dim)
     Bh = B_.reshape(B, s.n_groups, s.d_state)
     Ch = C_.reshape(B, s.n_groups, s.d_state)
     y, new_ssm = ops.ssd_decode_step(xh, dt, A, Bh, Ch, state["ssm"])
     y = y + xh * p["d_skip"][None, :, None].astype(y.dtype)
     y = y.reshape(B, di)
-    y = rms_norm_simple(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
+    y = gated_out_norm(y, z, p["out_norm"], cfg)
     out = (y @ p["out_proj"].astype(dt_c))[:, None]
     new_state = {"conv": window[:, 1:], "ssm": new_ssm}
+    if active is not None:
+        new_state = jax.tree.map(
+            lambda new, old: jnp.where(
+                active.reshape((B,) + (1,) * (new.ndim - 1)), new,
+                old.astype(new.dtype)), new_state, state)
     return out, new_state

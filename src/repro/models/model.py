@@ -124,7 +124,8 @@ class Model:
 
     def prefill_chunk(self, params: Params, batch: Dict[str, jax.Array],
                       caches: Params, start: jax.Array, new_len: jax.Array,
-                      page_table: Optional[jax.Array] = None):
+                      page_table: Optional[jax.Array] = None,
+                      state_slot: Optional[jax.Array] = None):
         """Prefill ONE chunk of a prompt, resuming from cached state.
 
         ``batch["tokens"]`` is the [B, C] chunk (possibly right-padded to a
@@ -134,6 +135,8 @@ class Model:
         request's pages and attention gathers the whole cached prefix;
         without it the chunk resumes a dense staging cache (attention over
         the cache prefix; SSM layers resume their carried conv/ssm state).
+        ``state_slot`` [1]: the slot whose recurrent state a paged chunk
+        resumes and leaves (the pattern family).
         Returns (last-valid-token logits [B, V], updated caches)."""
         cfg = self.cfg
         x = self._embed_inputs(params, batch)
@@ -142,7 +145,7 @@ class Model:
         x, _, caches = transformer.forward_stack(
             params["stack"], x, cfg, positions=positions, mode="prefill",
             caches=caches, cache_len=new_len, page_table=page_table,
-            chunked=True)
+            chunked=True, state_slot=state_slot)
         local_last = jnp.maximum(new_len - start - 1, 0).astype(jnp.int32)
         last = jnp.take_along_axis(x, local_last[:, None, None],
                                    axis=1)[:, 0]
@@ -151,15 +154,18 @@ class Model:
         return logits[:, 0], caches
 
     def decode_paged(self, params: Params, tokens: jax.Array, caches: Params,
-                     page_table: jax.Array, cache_len: jax.Array):
+                     page_table: jax.Array, cache_len: jax.Array,
+                     active: Optional[jax.Array] = None):
         """One decode step against paged KV pools.  tokens: [B] int32 →
         (logits [B, V], caches); the new token's KV is appended at
-        ``cache_len`` through the page table."""
+        ``cache_len`` through the page table.  Recurrent state advances
+        only in the ``active`` [B] rows."""
         cfg = self.cfg
         x = apply_embedding(params["embed"], tokens[:, None], cfg)
         x, _, caches = transformer.forward_stack(
             params["stack"], x, cfg, positions=None, mode="decode",
-            caches=caches, cache_len=cache_len, page_table=page_table)
+            caches=caches, cache_len=cache_len, page_table=page_table,
+            active=active)
         logits = apply_lm_head(params["embed"], params.get("head"), x, cfg)
         return logits[:, 0], caches
 

@@ -7,6 +7,9 @@ moe             : n_dense × [attn + MLP] then (L−n_dense) × [attn + MoE]
 ssm             : L × [mamba2]
 hybrid (zamba2) : ⌊L/e⌋ super-blocks of (e × mamba2 + 1 shared attn+MLP
                   application, weights shared) + (L mod e) trailing mamba2
+pattern (nemotron-h) : ``layer_pattern``, one pre-norm mixer per layer with
+                  its own weights — mamba2 (M), MLP (-) or attention (*);
+                  each run of one kind is stacked and scanned
 
 All stacks run in three modes sharing one code path:
   train   — no cache;
@@ -106,15 +109,27 @@ def attn_block(bp: Params, x, cfg: ModelConfig, *, positions, mode: str,
     return x, aux, new_cache
 
 
-def mamba_block(bp: Params, x, cfg: ModelConfig, *, mode: str, state=None):
+def mamba_block(bp: Params, x, cfg: ModelConfig, *, mode: str, state=None,
+                valid_len=None, active=None):
     x = shard(x, "batch", "act_seq", "embed")
     h = apply_norm(bp["norm"], x, cfg)
     if mode == "decode":
-        out, new_state = mamba2.decode_step_mamba2(bp["mamba"], h, cfg, state)
+        out, new_state = mamba2.decode_step_mamba2(bp["mamba"], h, cfg, state,
+                                                   active=active)
     else:
         out, new_state = mamba2.apply_mamba2(
-            bp["mamba"], h, cfg, state=state if mode == "prefill" else None)
+            bp["mamba"], h, cfg, state=state if mode == "prefill" else None,
+            valid_len=valid_len)
     return x + out, new_state
+
+
+def init_pattern_layer(key, cfg: ModelConfig, kind: str) -> Params:
+    """One layer of a ``layer_pattern``: its norm and its one mixer."""
+    if kind == "M":
+        return init_mamba_block(key, cfg)
+    if kind == "-":
+        return {"mlp": init_mlp(key, cfg), "norm": init_norm(cfg)}
+    return {"attn": attention.init_attention(key, cfg), "norm": init_norm(cfg)}
 
 
 # ---------------------------------------------------------------------------
@@ -167,6 +182,13 @@ def init_stack(key, cfg: ModelConfig) -> Params:
             p["tail_blocks"] = _stack_init(lambda k: init_mamba_block(k, cfg),
                                            ks[2], rem)
         return p
+    if fam == "pattern":
+        runs = cfg.pattern_runs
+        return {"runs": [
+            _stack_init(functools.partial(init_pattern_layer, cfg=cfg,
+                                          kind=kind), k, n)
+            for (kind, n), k in zip(runs, jax.random.split(ks[0], len(runs)))],
+            "final_norm": init_norm(cfg)}
     raise ValueError(fam)
 
 
@@ -179,13 +201,23 @@ def _stack_tree(tree, n: int):
 
 
 def init_paged_cache_tree(cfg: ModelConfig, num_pages: int, page_size: int,
-                          dtype=jnp.bfloat16) -> Params:
+                          dtype=jnp.bfloat16, slots: int = 0) -> Params:
     """Paged pools stacked along the layer axis (``[L, P, page, H, D]``
     leaves).  One page table row (owned by the serving engine) addresses
-    the same logical pages in every layer's pool.  Only full-attention
-    families page; stateful families keep the dense slot cache."""
+    the same logical pages in every layer's pool.  Full-attention families
+    page; the pattern family holds, beside its attention runs' pools, each
+    mamba2 run's recurrent state for ``slots`` rows under ``"mamba"``
+    (``[n, slots, ...]`` leaves).  Other stateful families keep the dense
+    slot cache."""
     fam = cfg.family
     one = attention.init_paged_pool(cfg, num_pages, page_size, dtype)
+    if fam == "pattern":
+        runs = cfg.pattern_runs
+        return {"attn": [_stack_tree(one, n) for k, n in runs if k == "*"],
+                # float32 SSM state; the conv tail in the compute dtype,
+                # the dtype its stream is made in
+                "mamba": [_stack_tree(mamba2.init_mamba2_state(
+                    cfg, slots, cfg.cdtype), n) for k, n in runs if k == "M"]}
     if fam in ("dense", "encoder"):
         return {"attn": _stack_tree(one, cfg.num_layers)}
     if fam == "moe":
@@ -224,6 +256,12 @@ def init_cache_tree(cfg: ModelConfig, batch: int, max_seq: int,
         if rem:
             c["mamba_tail"] = _stack_tree(mstate, rem)
         return c
+    if fam == "pattern":
+        runs = cfg.pattern_runs
+        astate = attention.init_cache(cfg, batch, max_seq, dtype)
+        return {"attn": [_stack_tree(astate, n) for k, n in runs if k == "*"],
+                "mamba": [_stack_tree(mamba2.init_mamba2_state(
+                    cfg, batch, cfg.cdtype), n) for k, n in runs if k == "M"]}
     raise ValueError(fam)
 
 
@@ -295,6 +333,72 @@ def _none_like(tree, n: int):
     return None
 
 
+def _pattern_layer(kind: str, bp, x, cache, cfg: ModelConfig, *, positions,
+                   mode, cache_len, page_table, chunked, valid_len, active):
+    """One layer of a ``layer_pattern``: x + mixer(norm(x))."""
+    if kind == "M":
+        return mamba_block(bp, x, cfg, mode=mode, state=cache,
+                           valid_len=valid_len, active=active)
+    x = shard(x, "batch", "act_seq", "embed")
+    h = apply_norm(bp["norm"], x, cfg)
+    if kind == "-":
+        return x + apply_mlp(bp["mlp"], h, cfg), None
+    out, new_cache = _attn_call(bp, h, cfg, positions, cache, cache_len, mode,
+                                page_table, chunked)
+    return x + out, new_cache
+
+
+def _forward_pattern(params, x, cfg: ModelConfig, *, positions, mode, caches,
+                     cache_len, page_table, chunked, state_slot, active):
+    """Walk ``cfg.layer_pattern`` run by run.  Caches are
+    ``{"attn": [...], "mamba": [...]}``, one entry per run of that kind in
+    pattern order.  With ``state_slot`` (paged chunked prefill, batch 1)
+    the mamba runs read and write row ``state_slot[0]`` of their state;
+    a paged decode's rows are the slots themselves."""
+    new_caches = {"attn": [], "mamba": []} if caches else None
+    # prefill chunks past the prompt's end are bucket padding
+    valid_len = cache_len - positions[:, 0] \
+        if mode == "prefill" and chunked else None
+    nxt = {"*": 0, "M": 0}
+    for blocks, (kind, n) in zip(params["runs"], cfg.pattern_runs):
+        key = {"*": "attn", "M": "mamba"}.get(kind)
+        c = None
+        if caches and key is not None:
+            c = caches[key][nxt[kind]]
+            nxt[kind] += 1
+        whole = c
+        if c is not None and kind == "M" and state_slot is not None:
+            c = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
+                a, state_slot[0], 1, axis=1), whole)
+
+        def body(x, xs, kind=kind):
+            bp, cache = xs
+            return _pattern_layer(kind, bp, x, cache, cfg,
+                                  positions=positions, mode=mode,
+                                  cache_len=cache_len, page_table=page_table,
+                                  chunked=chunked, valid_len=valid_len,
+                                  active=active)
+
+        body = _remat(body, cfg, mode)
+        if n > 1 and cfg.scan_layers:
+            x, nc = jax.lax.scan(body, x, (blocks, c))
+        else:
+            outs = []
+            for i in range(n):
+                x, o = body(x, jax.tree.map(lambda a: a[i], (blocks, c)))
+                outs.append(o)
+            nc = None if outs[0] is None else \
+                jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
+        if nc is None:
+            continue
+        nc = jax.tree.map(lambda new, old: new.astype(old.dtype), nc, c)
+        if kind == "M" and state_slot is not None:
+            nc = jax.tree.map(lambda a, r: jax.lax.dynamic_update_slice_in_dim(
+                a, r, state_slot[0], axis=1), whole, nc)
+        new_caches[key].append(nc)
+    return x, new_caches
+
+
 # ---------------------------------------------------------------------------
 # full stacks
 # ---------------------------------------------------------------------------
@@ -310,20 +414,31 @@ def forward_stack(
     cache_len: Optional[jax.Array] = None,
     page_table: Optional[jax.Array] = None,  # [B, MP] → paged attn caches
     chunked: bool = False,                   # prefill resumes a cached prefix
+    state_slot: Optional[jax.Array] = None,  # [1] slot of a paged chunk
+    active: Optional[jax.Array] = None,      # [B] decode rows that advance
 ) -> Tuple[jax.Array, jax.Array, Optional[Params]]:
-    """Returns (hidden, aux_loss, new_caches)."""
+    """Returns (hidden, aux_loss, new_caches).  ``state_slot`` and
+    ``active`` concern recurrent state (the pattern family) alone."""
     fam = cfg.family
     assert mode in ("train", "prefill", "decode", "verify")
     assert mode != "verify" or page_table is not None, \
         "verify mode is paged-only (speculative decoding)"
     if page_table is not None:
-        assert fam in ("dense", "encoder", "moe"), \
+        assert fam in ("dense", "encoder", "moe", "pattern"), \
             f"paged attention unsupported for family {fam!r}"
     if mode == "train":
         caches = None
     new_caches: Optional[Params] = None
 
-    if fam in ("dense", "encoder"):
+    if fam == "pattern":
+        assert mode != "verify", "the pattern family does not verify drafts"
+        aux = jnp.zeros((), jnp.float32)
+        x, new_caches = _forward_pattern(
+            params, x, cfg, positions=positions, mode=mode, caches=caches,
+            cache_len=cache_len, page_table=page_table, chunked=chunked,
+            state_slot=state_slot, active=active)
+
+    elif fam in ("dense", "encoder"):
         c = caches["attn"] if caches else None
         x, aux, nc = _scan_attn_blocks(
             params["blocks"], x, cfg, positions=positions, mode=mode,

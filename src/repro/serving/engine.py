@@ -7,8 +7,11 @@ Full-attention families serve from a **paged KV cache**
 ``ceil((prompt + max_new) / page_size)`` fixed-size pages instead of a
 whole ``max_seq`` row, decode gathers pages through per-request page
 tables (``kernels.paged_decode_attention``), and HBM accounting is
-pages-in-use.  Stateful families (SSM state, SWA ring buffers, MLA latent
-caches) keep the dense ``SlotKVCache``.
+pages-in-use.  The pattern family (mamba2 layers beside attention layers)
+pages too: each slot's recurrent state sits in the same pools, zeroed at
+admission, carried across prefill chunks and advanced by decode.  Other
+stateful families (SSM state, SWA ring buffers, MLA latent caches) keep the
+dense ``SlotKVCache``.
 
 Every tick is a **mixed prefill/decode tick**: queued prompts are split
 into fixed-size chunks (the pow2 prefill buckets double as chunk sizes)
@@ -52,10 +55,12 @@ Tracing: each phase of the loop thread runs under one flat host span
 (``core.telemetry.span``, args ``tick`` and, per request, ``rid``):
 ``engine.wait`` between ticks, ``engine.admit``, one ``engine.prefill``
 per chunk, ``engine.pages`` (decode-page growth), ``engine.decode`` (the
-dispatch), ``engine.sync`` (each blocking device-to-host read) and
-``engine.finish`` (bookkeeping after the sync); ``engine.submit`` marks
-an arrival on the caller's thread.  Under the profiler they share the
-device ops' clock, so device idle can be put down to a phase.
+dispatch), ``engine.sync`` (each blocking device-to-host read),
+``engine.finish`` (bookkeeping after the sync) and ``engine.state`` (a
+slot's recurrent state zeroed at admission or dropped at preemption);
+``engine.submit`` marks an arrival on the caller's thread.  Under the
+profiler they share the device ops' clock, so device idle can be put down
+to a phase.
 ``Request.token_times`` holds when each generated token was committed.
 
 SLO-aware admission: requests carry ``latency_slo_ms``; both the
@@ -224,7 +229,7 @@ class ServingEngine:
         self.buckets = _buckets(max_seq)
 
         # ---- data-plane selection: paged pools vs dense slots ----------
-        paged_capable = (cfg.family in ("dense", "moe")
+        paged_capable = (cfg.family in ("dense", "moe", "pattern")
                          and cfg.attn_type == "full"
                          and cfg.sliding_window == 0
                          and not cfg.encoder_only)
@@ -252,6 +257,11 @@ class ServingEngine:
                     "kv_dtype is a paged-data-plane knob; the dense slot "
                     "cache serves in the compute dtype")
             self.kv = SlotKVCache(cfg, max_slots, max_seq)
+        # slots hold recurrent state beside their pages: a shared page
+        # carries no state, so such a model shares no prefix
+        self.recurrent = self.paged and self.kv.has_state
+        self.state_resets = 0         # slot states zeroed at admission
+        self.state_replays = 0        # preempted recurrent requests requeued
 
         # ---- prefix sharing (paged only): radix index + COW accounting --
         # guarded by the engine lock like every other allocator structure;
@@ -259,7 +269,8 @@ class ServingEngine:
         # sanctioned reader outside it (match(touch=False), racy-tolerant)
         self.prefix: Optional[PrefixRadixIndex] = (
             PrefixRadixIndex(self.kv.page_size)
-            if self.paged and prefix_sharing else None)
+            if self.paged and prefix_sharing and not self.recurrent
+            else None)
         self.kv_prefix_hits = 0       # admissions that attached shared pages
         self.kv_prefix_misses = 0
         self.preemptions = 0          # page-pressure requeues
@@ -333,6 +344,10 @@ class ServingEngine:
                 raise ValueError(
                     "speculative decoding needs the paged data plane "
                     f"(family={cfg.family!r}, attn={cfg.attn_type!r})")
+            if self.recurrent:
+                raise ValueError(
+                    "speculative decoding cannot wind recurrent state back "
+                    f"past rejected drafts (model {cfg.name!r})")
             if draft_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab {draft_cfg.vocab_size} != target vocab "
@@ -373,7 +388,7 @@ class ServingEngine:
     def _stateful(self) -> bool:
         """Families whose prefill must not see pad tokens (SSM state / SWA
         ring cache) → exact-length prefill instead of pow2 buckets."""
-        return self.cfg.family in ("ssm", "hybrid") or \
+        return self.cfg.family in ("ssm", "hybrid", "pattern") or \
             self.cfg.sliding_window > 0
 
     def _prefill_fn(self, params, tokens, last_index, *, bucket: int):
@@ -385,11 +400,13 @@ class ServingEngine:
         return logits, caches, clen
 
     def _chunk_paged_fn(self, params, pools, tokens, table_row, start,
-                        new_len):
-        """One prefill chunk straight into the request's pages."""
+                        new_len, slot=None):
+        """One prefill chunk straight into the request's pages (and, for a
+        recurrent model, its slot's state: ``slot`` [1])."""
         return self.model.prefill_chunk(params, {"tokens": tokens}, pools,
                                         start, new_len,
-                                        page_table=table_row)
+                                        page_table=table_row,
+                                        state_slot=slot)
 
     def _chunk_stateful_fn(self, params, staging, tokens, start, new_len):
         """One exact-length chunk resuming a batch-1 staging cache."""
@@ -406,7 +423,8 @@ class ServingEngine:
     def _decode_paged_fn(self, params, pools, page_table, tokens, cache_len,
                          active):
         logits, pools = self.model.decode_paged(params, tokens, pools,
-                                                page_table, cache_len)
+                                                page_table, cache_len,
+                                                active=active)
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         next_tokens = jnp.where(active, next_tokens, tokens)
         new_len = jnp.where(active, cache_len + 1, cache_len)
@@ -457,6 +475,9 @@ class ServingEngine:
             # compiled here too
             zero1 = jnp.asarray([0], jnp.int32)
             if self.paged:
+                # a recurrent chunk reads and writes slot 0's state, which
+                # no-token chunks leave as it was
+                slot = (zero1,) if self.recurrent else ()
                 row = jnp.zeros((1, self.kv.pages_per_slot), jnp.int32)
                 logits = None
                 for b in self.chunk_buckets:
@@ -469,7 +490,7 @@ class ServingEngine:
                         logits, pools = self._chunk(
                             self.params, self.kv.pools,
                             jnp.zeros((1, b), jnp.int32),
-                            row[:, :kv_pages], zero1, zero1)
+                            row[:, :kv_pages], zero1, zero1, *slot)
                         self.kv.pools = pools
                 # absorb the first-token host programs (argmax, table/len
                 # scatters) and the eager page-table updates of decode-page
@@ -483,6 +504,8 @@ class ServingEngine:
                     self.kv.install(0, row, 0)
                     self.kv.page_table = self.kv.page_table.at[0, 0].set(0)
                     self.kv.page_table = self.kv.page_table.at[0].set(0)
+                    if self.recurrent:
+                        self.kv.reset_state(0)
                 toks, pools, clen = self._decode(
                     self.params, self.kv.pools, self.kv.page_table,
                     self.last_tokens, self.kv.cache_len,
@@ -552,12 +575,14 @@ class ServingEngine:
         kvp = self._kv_span_pages(next(s for s in self.buckets if s >= b))
         row = jnp.zeros((1, self.kv.pages_per_slot), jnp.int32)
         zero1 = jnp.zeros((1,), jnp.int32)
+        slot = (zero1,) if self.recurrent else ()
         chunk_wall = decode_wall = float("inf")
         for _ in range(2):                       # min-of-2: absorb jitter
             t = time.monotonic()
             logits, pools = self._chunk(
                 self.params, self.kv.pools,
-                jnp.zeros((1, b), jnp.int32), row[:, :kvp], zero1, zero1)
+                jnp.zeros((1, b), jnp.int32), row[:, :kvp], zero1, zero1,
+                *slot)
             self.kv.pools = pools
             jax.block_until_ready(logits)
             chunk_wall = min(chunk_wall, time.monotonic() - t)
@@ -903,6 +928,11 @@ class ServingEngine:
                 req.slot, req.table_row = got
                 req.shared_nodes = pins
                 req.kv_shared_tokens = w
+                if self.recurrent:
+                    with span("engine.state", tick=self.ticks, rid=req.rid,
+                              slot=req.slot, what="reset"):
+                        self.kv.reset_state(req.slot)
+                    self.state_resets += 1
                 if self.prefix is not None:
                     if w:
                         self.kv_prefix_hits += 1
@@ -964,11 +994,13 @@ class ServingEngine:
                     # max_seq — the sliced row's width keys the (chunk,
                     # span) compile
                     kv_pages = self._kv_span_pages(start + real)
+                    slot = (jnp.asarray([req.slot], jnp.int32),) \
+                        if self.recurrent else ()
                     logits, pools = self._chunk(
                         self.params, self.kv.pools, jnp.asarray(padded),
                         req.table_row[:, :kv_pages],
                         jnp.asarray([start], jnp.int32),
-                        jnp.asarray([start + real], jnp.int32))
+                        jnp.asarray([start + real], jnp.int32), *slot)
                     self.kv.pools = pools
                 elif self._chunkable_stateful:
                     toks = jnp.asarray(req.prompt[None, start:start + real],
@@ -1080,9 +1112,16 @@ class ServingEngine:
         """Preempt via the existing requeue path: release the victim's
         capacity and re-run it from scratch at the queue head.  Its future
         stays pending (never a drop) and greedy decode is deterministic,
-        so the re-run reproduces the same tokens."""
+        so the re-run reproduces the same tokens.  A recurrent request's
+        state goes with its slot: it re-prefills from its start."""
         self.preemptions += 1
-        self._release(victim)
+        if self.recurrent:
+            with span("engine.state", tick=self.ticks, rid=victim.rid,
+                      slot=victim.slot, what="drop"):
+                self._release(victim)
+            self.state_replays += 1
+        else:
+            self._release(victim)
         self.active.pop(victim.rid, None)
         victim.phase = "queued"
         victim.pos = 0
@@ -1470,6 +1509,9 @@ class ServingEngine:
                 out["kv_prefix_misses"] = self.kv_prefix_misses
                 out["preemptions"] = self.preemptions
                 out["decode_stalls"] = self.decode_stalls
+                out["state_bytes"] = self.kv.state_bytes()
+                out["state_resets"] = self.state_resets
+                out["state_replays"] = self.state_replays
                 out["kv_shared_pages_attached"] = sum(
                     self.kv.slot_shared.values())
                 if self.prefix is not None:

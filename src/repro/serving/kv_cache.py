@@ -25,6 +25,14 @@ trash-page story for masked writes is unchanged.  ``bytes_in_use`` counts
 each physical page once, which makes admission and
 ``dynamic_footprint_bytes`` automatically *marginal* (post-sharing).
 
+Recurrent state (the pattern family's mamba2 layers) lives in the same
+``pools`` tree, under ``"mamba"``: each slot holds its conv tail and SSM
+state beside the attention pages.  A slot's state is zeroed when a request
+is admitted to it (``reset_state``), carried across that request's prefill
+chunks and advanced by its decode steps; a freed slot's state is left for
+the next admission to zero.  Pages carry no state, so such a model shares
+no prefix.
+
 ``SlotKVCache`` keeps the original dense design for the stateful families
 (SSM state / SWA ring buffers / MLA latent caches), where the per-layer
 cache is already recurrent-state- or window-bounded and paging the
@@ -168,7 +176,7 @@ class PagedKVCache:
                 f"({self.pages_per_slot} pages) plus the trash page")
         self.num_pages = num_pages
         self.pools = transformer.init_paged_cache_tree(
-            cfg, num_pages, page_size, dtype)
+            cfg, num_pages, page_size, dtype, slots=max_slots)
         self.page_table = jnp.zeros((max_slots, self.pages_per_slot),
                                     jnp.int32)
         self.cache_len = jnp.zeros((max_slots,), jnp.int32)
@@ -184,8 +192,16 @@ class PagedKVCache:
         self.slot_shared: Dict[int, int] = {}
         self.cow_copies = 0
         self._copy_page_fn = None
+        self._reset_state_fn = None
         self._capacity_bytes = _tree_bytes(self.pools)
-        self._page_bytes = self._capacity_bytes // num_pages
+        state_bytes = _tree_bytes(self.pools.get("mamba", []))
+        self.state_bytes_per_slot = state_bytes // max_slots
+        self._page_bytes = (self._capacity_bytes - state_bytes) // num_pages
+
+    @property
+    def has_state(self) -> bool:
+        """Whether slots hold recurrent state beside their pages."""
+        return self.state_bytes_per_slot > 0
 
     # ------------------------------------------------------------- queries
     def pages_needed(self, n_tokens: int) -> int:
@@ -214,12 +230,18 @@ class PagedKVCache:
     def capacity_bytes(self) -> int:
         return self._capacity_bytes
 
+    def state_bytes(self) -> int:
+        """Recurrent state held by the slots in use."""
+        return (self.max_slots - len(self.free_slots)) \
+            * self.state_bytes_per_slot
+
     def bytes_in_use(self) -> int:
-        return self.pages_in_use() * self._page_bytes
+        return self.pages_in_use() * self._page_bytes + self.state_bytes()
 
     def dense_equivalent_bytes(self) -> int:
         """What the dense ``max_slots × max_seq`` cache would allocate."""
-        return self.max_slots * self.pages_per_slot * self._page_bytes
+        return self.max_slots * (self.pages_per_slot * self._page_bytes
+                                 + self.state_bytes_per_slot)
 
     # --------------------------------------------------------- refcounting
     def _take_page(self) -> int:
@@ -287,11 +309,26 @@ class PagedKVCache:
         Indices stay traced so one compilation covers all (src, dst)."""
         if self._copy_page_fn is None:
             def _copy(pools, s, d):
-                return jax.tree.map(
-                    lambda a: a.at[:, d].set(a[:, s]), pools)
+                return {k: v if k == "mamba" else jax.tree.map(
+                    lambda a: a.at[:, d].set(a[:, s]), v)
+                    for k, v in pools.items()}
             self._copy_page_fn = jax.jit(_copy, donate_argnums=(0,))
         self.pools = self._copy_page_fn(
             self.pools, jnp.int32(src), jnp.int32(dst))
+
+    def reset_state(self, slot: int):
+        """Zero ``slot``'s recurrent state (slot axis 1 of each ``[n,
+        slots, ...]`` leaf) for the request just admitted to it.  The index
+        stays traced so one compilation covers every slot."""
+        if self._reset_state_fn is None:
+            def _reset(pools, s):
+                out = dict(pools)
+                out["mamba"] = jax.tree.map(
+                    lambda a: a.at[:, s].set(jnp.zeros((), a.dtype)),
+                    pools["mamba"])
+                return out
+            self._reset_state_fn = jax.jit(_reset, donate_argnums=(0,))
+        self.pools = self._reset_state_fn(self.pools, jnp.int32(slot))
 
     def append_page(self, slot: int) -> Optional[int]:
         """Grow an installed slot by one private page (on-demand decode

@@ -223,3 +223,71 @@ def test_engine_programs_keep_their_names(exact_config):
                              jnp.zeros((1, 16), jnp.int32), row, zero1, zero1)
     assert decode.as_text().startswith("module @jit__decode_paged_fn ")
     assert chunk.as_text().startswith("module @jit__chunk_paged_fn ")
+
+
+# the hybrid cell (nemotron-h-47b.l11.gen): 16 slots x 8,192 tokens, page 16
+HYBRID_SLOTS, HYBRID_MAX_SEQ = 16, 8192
+HYBRID_CHIP_BYTES = 15.75e9             # a v5e's usable HBM
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_pattern_engine_programs_compile_at_cell_shapes(one_chip, program):
+    """The hybrid cell's decode and 64-token prefill-chunk programs
+    (``ServingEngine._decode_paged_fn``, ``_chunk_paged_fn`` at the widest
+    KV span) at its published widths, pattern, slots and context compile
+    for the chip within its memory; the chunk program holds the SSD scan
+    and flash attention, the decode program the paged decode kernel."""
+    import json
+    import pathlib
+    import sys
+
+    from repro.models import transformer
+    from repro.models.model import build_model
+    from repro.serving.engine import ServingEngine
+
+    bench = pathlib.Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from benchlib import spec
+
+    conf = json.loads((bench / "configs" / "nemotron-h-47b.l11.json")
+                      .read_text())
+    assert conf["serving"]["max_slots"] == HYBRID_SLOTS
+    assert conf["serving"]["max_seq"] == HYBRID_MAX_SEQ
+    cfg = spec.load_architecture(conf).model_config(conf)
+    model = build_model(cfg)
+    mp = HYBRID_MAX_SEQ // PAGE
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(model.init_abstract())
+    pools = on_chip(jax.eval_shape(
+        lambda: transformer.init_paged_cache_tree(
+            cfg, HYBRID_SLOTS * mp + 1, PAGE, jnp.bfloat16,
+            slots=HYBRID_SLOTS)))
+    ns = types.SimpleNamespace(model=model)
+    i32 = functools.partial(_sds, one_chip, dtype=jnp.int32)
+    if program == "decode":
+        fn = functools.partial(ServingEngine._decode_paged_fn, ns)
+        args = (params, pools, i32((HYBRID_SLOTS, mp)), i32((HYBRID_SLOTS,)),
+                i32((HYBRID_SLOTS,)),
+                _sds(one_chip, (HYBRID_SLOTS,), jnp.bool_))
+        names = ["paged_decode_attention"]
+    else:
+        fn = functools.partial(ServingEngine._chunk_paged_fn, ns)
+        args = (params, pools, i32((1, 64)), i32((1, mp)), i32((1,)),
+                i32((1,)), i32((1,)))
+        names = ["ssd_scan", "flash_attention"]
+    ops.set_impl("pallas")
+    try:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    finally:
+        ops.set_impl(None)
+    calls = _CUSTOM_CALL.findall(compiled.as_text())
+    assert sorted(set(calls)) == sorted(names), calls
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < HYBRID_CHIP_BYTES, total
